@@ -35,7 +35,6 @@ __all__ = ["ladder_sum", "ladder_logsumexp", "ladder_matvec",
            "rounding_barrier", "fma_fence"]
 
 
-@jax.custom_batching.custom_vmap
 def rounding_barrier(x: jnp.ndarray) -> jnp.ndarray:
     """Identity that discourages FMA contraction across it (best effort).
 
@@ -44,15 +43,13 @@ def rounding_barrier(x: jnp.ndarray) -> jnp.ndarray:
     ``a - b * c`` can disagree by an ulp.  An ``optimization_barrier``
     around the product keeps HLO passes from fusing mul and add into one
     cluster... usually.  The barrier is *expanded away* late in the XLA
-    pipeline, and empirically (jax 0.4.37, CPU) the vmapped interpret-
-    mode Pallas grid program still contracts straight through it — the
-    recorded product is rounded once while the consuming ``sub`` sees
-    the unrounded product.  Where the contraction provably flips
-    downstream selections, use ``fma_fence`` instead; this barrier
-    remains on the ladder inputs as cheap extra friction.  The
-    ``custom_vmap`` wrapper exists because the primitive has no batching
-    rule in this JAX version: under ``vmap`` the barrier is simply
-    applied to the batched array (the semantics are elementwise).
+    pipeline, and empirically (CPU) the vmapped interpret-mode Pallas
+    grid program still contracts straight through it — the recorded
+    product is rounded once while the consuming ``sub`` sees the
+    unrounded product.  Where the contraction provably flips downstream
+    selections, use ``fma_fence`` instead; this barrier remains on the
+    ladder inputs as cheap extra friction.  Inside a compiled TPU kernel
+    the barrier lowers to an identity (``repro.kernels.platform``).
     """
     return jax.lax.optimization_barrier(x)
 
@@ -86,11 +83,6 @@ def fma_fence(x: jnp.ndarray) -> jnp.ndarray:
     """
     a = jnp.abs(x) + 1.0
     return x / rounding_barrier(a / a)
-
-
-@rounding_barrier.def_vmap
-def _rounding_barrier_vmap(axis_size, in_batched, x):
-    return rounding_barrier(x), in_batched[0]
 
 
 def ladder_sum(x: jnp.ndarray, axis: int = -1) -> jnp.ndarray:

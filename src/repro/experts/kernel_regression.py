@@ -6,6 +6,9 @@ Prediction is ``k(x, A) @ alpha`` — the client-side compute hotspot, which
 is what `repro.kernels.kernel_gram` accelerates (this module's `predict`
 routes through it).
 
+The pool (`repro.experts.pool`) fits and predicts at full float32 matmul
+precision; see there.
+
 Kernel families (paper §IV):
   gaussian   exp(-gamma ||x - a||^2)        gamma in {0.01, 0.1, 1, 10, 100}
   laplacian  exp(-gamma ||x - a||_1)        same gammas

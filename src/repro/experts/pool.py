@@ -10,10 +10,18 @@ Every expert is pre-trained on the same 10% split ("pre-trained models can
 be trained on publicly available data without observing clients' data").
 Transmission cost c_k = n_params_k / max_k n_params_k, so max cost = 1
 (paper §IV), and the budget is B = 3.
+
+Both entry points fit and predict at full float32 matmul precision
+(``HIGHEST``): a TPU's default rounds float32 matmul operands to bfloat16,
+which the ill-conditioned ridge solve and the large-coefficient kernel
+predictions amplify far past float32 rounding, and which the MLPs'
+contractions carry too.  On the CPU float32 matmuls are exact to the dtype
+already, so the setting changes nothing there.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Sequence
 
 import numpy as np
@@ -30,12 +38,22 @@ GAMMAS = (0.01, 0.1, 1.0, 10.0, 100.0)
 DEGREES = (1.0, 2.0, 3.0, 4.0, 5.0)
 
 
+def _full_f32(fn):
+    """Run ``fn`` with float32 matmuls at ``HIGHEST`` precision."""
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+    return wrapped
+
+
 class ExpertPool(NamedTuple):
     experts: tuple                 # KernelExpert | MLPExpert, length K
     names: tuple                   # str labels
     costs: jnp.ndarray             # (K,) normalized transmission costs
 
 
+@_full_f32
 def build_paper_pool(x_pre: np.ndarray, y_pre: np.ndarray,
                      seed: int = 0,
                      subsample_anchors: int | None = None) -> ExpertPool:
@@ -76,6 +94,7 @@ def build_paper_pool(x_pre: np.ndarray, y_pre: np.ndarray,
     return ExpertPool(tuple(experts), tuple(names), costs)
 
 
+@_full_f32
 def pool_predict_all(pool: ExpertPool, x: np.ndarray,
                      use_pallas: bool = False,
                      clip: float | None = 5.0) -> jnp.ndarray:

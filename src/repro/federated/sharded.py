@@ -24,12 +24,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # jax 0.4.x keeps it in jax.experimental
-    from jax.experimental.shard_map import shard_map
 
 from repro.core.numerics import ladder_matvec, ladder_sum
 

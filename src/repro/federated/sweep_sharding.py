@@ -131,14 +131,9 @@ def sharded_sweep_fn(scan_config_fn, mesh: Mesh, scheduled: bool = False):
     # axis every output is gather-replicated over it (sharded_window_eval),
     # so one copy per sweep shard is the whole answer.  Replication
     # checking is disabled because jax cannot track replication through
-    # this scan-of-vmap; the kwarg spelling differs across jax versions
-    # (0.4.x check_rep, 0.7+ check_vma), hence the fallback.
-    try:
-        mapped = shard_map(per_shard, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_spec, check_rep=False)
-    except TypeError:
-        mapped = shard_map(per_shard, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_spec, check_vma=False)
+    # this scan-of-vmap.
+    mapped = shard_map(per_shard, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_spec, check_vma=False)
     fn = jax.jit(mapped)
 
     def call(preds, y, costs, keys, budgets, sched=None):

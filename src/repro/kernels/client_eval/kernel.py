@@ -6,16 +6,19 @@ squared-loss accumulators, and the FedBoost mixture gradient — into a
 single pass over the round's (K, W) prediction tile, replacing the ~6
 small ops the unfused round body dispatches per round.
 
-TPU mapping: the extended stream (K, n_stream + W) and targets ride in
-as whole-array VMEM operands — at the paper scale (K=22, n_stream=6000,
-f32) that is ~540 KiB, far under the ~16 MiB VMEM budget — and the round
-window is a *dynamic-start* contiguous load ``preds[:, ds(cursor, W)]``
-(wrap-free thanks to the W-column extension; see ``ref.extend_stream``).
-The cursor / client-count scalars arrive as (1, 1) operands.  All
-downstream compute is one (1, K) x (K, W) MXU matvec plus VPU
-elementwise/reduction work, so a single grid step suffices; streams too
-large for VMEM residency would move ``preds`` to HBM with an async-DMA'd
-window (future work, not needed at paper scale).
+TPU mapping: the extended stream (K, extended_width) and targets ride
+in as whole-array VMEM operands — at the paper's largest stream (K=22,
+n_stream=17,761, f32) that is ~1.7 MiB, under the 16 MiB scoped VMEM
+budget — and the round window is a *dynamic-start* contiguous load,
+wrap-free thanks to the W-column extension (see ``ref.extend_stream``).
+Interpret mode loads exactly ``preds[:, ds(cursor, W)]``; Mosaic loads
+lane windows only from multiples of 128, so the compiled kernel loads
+the aligned ``window_frame(W)``-wide frame holding the window and masks
+the columns outside it.  The cursor / client-count scalars arrive as
+(1, 1) operands.  All downstream compute is one (1, K) x (K, F) MXU
+matvec plus VPU elementwise/reduction work, so a single grid step
+suffices; streams too large for VMEM residency would move ``preds`` to
+HBM with an async-DMA'd window (future work).
 
 The grid is a singleton, which also keeps ``jax.vmap`` batching (the
 engine's sweep path) a *single* batched-grid dispatch per round rather
@@ -28,6 +31,8 @@ interpret mode on CPU executes the same XLA ops, so fused-vs-unfused
 round trajectories agree to float32 rounding (empirically bit-equal
 selection masks on the paper config — pinned by the benchmark's
 ``fused_trajectories_identical`` field and ``tests/test_client_eval.py``).
+Compiled on a TPU, plain reductions replace the ladders and the kernel
+agrees with ``ref`` to float32 rounding, not bit for bit.
 """
 
 from __future__ import annotations
@@ -40,7 +45,8 @@ from jax.experimental import pallas as pl
 
 from repro.core.numerics import ladder_matvec, ladder_sum
 
-from .ref import WEIGHTINGS, mix_weights_ref
+from ..platform import prepare_mosaic
+from .ref import LANES, WEIGHTINGS, mix_weights_ref, window_frame
 
 __all__ = ["client_eval_pallas"]
 
@@ -50,21 +56,43 @@ def _client_eval_kernel(preds_ref, y_ref, cursor_ref, nt_ref, w_ref,
                         ml_ref, grad_ref,
                         *, loss_scale: float, window: int, weighting: str,
                         with_grad: bool, interpret: bool):
-    # preds_ref: (K, S+W); y_ref: (1, S+W); cursor/nt: (1, 1) int32;
-    # w_ref/sel_ref: (1, K); outputs: mix/ml/grad (1, K), scal (1, 2).
-    # active_ref (1, W) int32 / shift_ref (1, 1) f32 are the optional
-    # per-round schedule operands (repro.scenarios) — ``None`` on the
-    # stationary path, which then traces exactly the pre-scenario ops.
+    # preds_ref: (K, L); y_ref: (1, L); cursor/nt: (1, 1) int32;
+    # w_ref/sel_ref: (1, K); outputs: mix (1, K), scal (1, 2), ml/grad
+    # (K, 1).  active_ref (1, W) int32 / shift_ref (1, 1) f32 are the
+    # optional per-round schedule operands (repro.scenarios) — ``None`` on
+    # the stationary path, which then traces exactly the pre-scenario ops.
     cursor = cursor_ref[0, 0]
     n_t = nt_ref[0, 0]
-    pw = preds_ref[:, pl.ds(cursor, window)]            # (K, W) gather
-    yw = y_ref[:, pl.ds(cursor, window)]                # (1, W)
+    if interpret:
+        # the exact window, gathered as the unfused path gathers it
+        pw = preds_ref[:, pl.ds(cursor, window)]        # (K, W)
+        yw = y_ref[:, pl.ds(cursor, window)]            # (1, W)
+        offs = jax.lax.broadcasted_iota(jnp.int32, (1, window), 1)
+        cmask = offs < n_t
+    else:
+        # Mosaic loads a lane window only from a multiple of 128: load
+        # the aligned frame that holds the window and mask the rest (the
+        # wrapper places ``active`` at the same offset in the frame)
+        frame = window_frame(window)
+        off = cursor % LANES
+        start = pl.multiple_of(cursor - off, LANES)
+        pw = preds_ref[:, pl.ds(start, frame)]          # (K, F)
+        yw = y_ref[:, pl.ds(start, frame)]              # (1, F)
+        offs = jax.lax.broadcasted_iota(jnp.int32, (1, frame), 1) - off
+        cmask = (offs >= 0) & (offs < n_t)
     if shift_ref is not None:
         yw = yw + shift_ref[0, 0]                       # concept drift
-    offs = jax.lax.broadcasted_iota(jnp.int32, (1, window), 1)
-    cmask = offs < n_t                                  # (1, W)
     if active_ref is not None:
         cmask = cmask & (active_ref[...] != 0)          # participation
+
+    def lane_sum(x):                                    # (R, n) -> (R, 1)
+        if interpret:
+            # ladder reductions: same fixed add tree as the unfused
+            # ``client_window_losses`` (see repro.core.numerics) so the
+            # two execution strategies stay bit-equal in every fusion
+            # context
+            return ladder_sum(x, axis=1)[:, None]
+        return jnp.sum(x, axis=1, keepdims=True)
 
     w = w_ref[...]                                      # (1, K)
     sel = sel_ref[...] != 0
@@ -75,47 +103,37 @@ def _client_eval_kernel(preds_ref, y_ref, cursor_ref, nt_ref, w_ref,
     mix_ref[...] = mix.astype(mix_ref.dtype)
 
     sq = (pw - yw) ** 2                                 # (K, W) broadcast
-    # ladder reductions: same fixed add tree as the unfused
-    # ``client_window_losses`` (see repro.core.numerics) so the two
-    # execution strategies stay bit-equal in every fusion context
-    ml = ladder_sum(
-        jnp.where(cmask, jnp.minimum(sq / loss_scale, 1.0), 0.0), axis=1)
-    ml_ref[...] = ml[None, :].astype(ml_ref.dtype)
+    ml_ref[...] = lane_sum(
+        jnp.where(cmask, jnp.minimum(sq / loss_scale, 1.0), 0.0)
+    ).astype(ml_ref.dtype)
 
     if interpret:
         yhat = ladder_matvec(mix, pw)                   # (1, W)
     else:
-        # MXU-friendly contraction for compiled TPU (never
-        # bit-comparable to the CPU path in the first place)
-        yhat = jnp.dot(mix, pw, preferred_element_type=jnp.float32)
+        # the MXU at full float32 precision (the default would round the
+        # operands to bfloat16)
+        yhat = jnp.dot(mix, pw, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
     ens_sq = jnp.where(cmask, (yhat - yw) ** 2, 0.0)
     if active_ref is None:
         nf = n_t.astype(ens_sq.dtype)
     else:
         # means divide by the SURVIVING client count (clamped >= 1 —
         # slot 0 is always compiled active, see Participation.mask)
-        nf = jnp.maximum(jnp.sum(cmask.astype(jnp.int32)), 1).astype(
-            ens_sq.dtype)
-    ens_sq_mean = ladder_sum(ens_sq[0]) / nf
-    ens_norm = ladder_sum(jnp.minimum(ens_sq[0] / loss_scale, 1.0))
-    scal_ref[...] = jnp.stack([ens_sq_mean, ens_norm]).reshape(1, 2).astype(
-        scal_ref.dtype)
+        nf = jnp.maximum(jnp.sum(cmask.astype(jnp.int32), axis=1,
+                                 keepdims=True), 1).astype(ens_sq.dtype)
+    scal_ref[:, 0:1] = (lane_sum(ens_sq) / nf).astype(scal_ref.dtype)
+    scal_ref[:, 1:2] = lane_sum(
+        jnp.minimum(ens_sq / loss_scale, 1.0)).astype(scal_ref.dtype)
 
     if with_grad:
+        # the FedBoost alpha trajectory feeds back on itself, so on the
+        # CPU even a 1-ulp difference from the unfused
+        # ``fedboost_window_grad`` would amplify over rounds: the
+        # interpreter takes its ladder contraction
         resid = jnp.where(cmask, yhat - yw, 0.0)        # (1, W)
-        if interpret:
-            # Same fixed-order ladder contraction as the unfused
-            # ``fedboost_window_grad``: the FedBoost alpha trajectory
-            # feeds back on itself, so even a 1-ulp difference here
-            # amplifies over rounds.
-            grad = (2.0 / nf) * ladder_sum(pw * resid, axis=1)
-            grad_ref[...] = grad[None, :].astype(grad_ref.dtype)
-        else:
-            # MXU-friendly rank-2 form for compiled TPU (which is never
-            # bit-comparable to the CPU path in the first place).
-            grad = (2.0 / nf) * jnp.dot(pw, resid.T,
-                                        preferred_element_type=jnp.float32)
-            grad_ref[...] = grad.T.astype(grad_ref.dtype)
+        grad = (2.0 / nf) * lane_sum(pw * resid)        # (K, 1)
+        grad_ref[...] = grad.astype(grad_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("loss_scale", "window",
@@ -129,8 +147,8 @@ def client_eval_pallas(preds_ext: jnp.ndarray, y_ext: jnp.ndarray,
                        interpret: bool = True, active=None, shift=None):
     """Fused client-eval launch.
 
-    ``preds_ext``: (K, n_stream + window) f32; ``y_ext``:
-    (n_stream + window,) f32; ``cursor``/``n_t``: int32 scalars;
+    ``preds_ext``: (K, extended_width) f32; ``y_ext``: (extended_width,)
+    f32 (see ``ref.extend_stream``); ``cursor``/``n_t``: int32 scalars;
     ``w``/``sel``: (K,).  Returns ``(mix, ens_sq_mean, ens_norm,
     model_losses, grad)`` with ``grad = None`` when ``with_grad`` is off
     (the EFL-FG path needs no mixture gradient).
@@ -146,6 +164,8 @@ def client_eval_pallas(preds_ext: jnp.ndarray, y_ext: jnp.ndarray,
     if (active is None) != (shift is None):
         raise ValueError("schedule operands come together: pass both "
                          "active and shift, or neither")
+    if not interpret:
+        prepare_mosaic()
     scheduled = active is not None
     K, SW = preds_ext.shape
     kern = functools.partial(_client_eval_kernel, loss_scale=loss_scale,
@@ -156,11 +176,11 @@ def client_eval_pallas(preds_ext: jnp.ndarray, y_ext: jnp.ndarray,
     out_shape = [
         jax.ShapeDtypeStruct((1, K), jnp.float32),   # mix
         jax.ShapeDtypeStruct((1, 2), jnp.float32),   # [ens_sq_mean, ens_norm]
-        jax.ShapeDtypeStruct((1, K), jnp.float32),   # model_losses
-        jax.ShapeDtypeStruct((1, K), jnp.float32),   # grad
+        jax.ShapeDtypeStruct((K, 1), jnp.float32),   # model_losses
+        jax.ShapeDtypeStruct((K, 1), jnp.float32),   # grad
     ]
     out_specs = [pl.BlockSpec((1, K), full), pl.BlockSpec((1, 2), full),
-                 pl.BlockSpec((1, K), full), pl.BlockSpec((1, K), full)]
+                 pl.BlockSpec((K, 1), full), pl.BlockSpec((K, 1), full)]
     if not with_grad:
         out_shape, out_specs = out_shape[:3], out_specs[:3]
     in_specs = [
@@ -178,9 +198,15 @@ def client_eval_pallas(preds_ext: jnp.ndarray, y_ext: jnp.ndarray,
                 jnp.asarray(w, jnp.float32).reshape(1, K),
                 jnp.asarray(sel, jnp.int32).reshape(1, K)]
     if scheduled:
-        in_specs += [pl.BlockSpec((1, window), full),
+        active = jnp.asarray(active, jnp.int32)
+        if not interpret:
+            # place the mask where the window sits in the kernel's frame
+            active = jax.lax.dynamic_update_slice(
+                jnp.zeros(window_frame(window), jnp.int32), active,
+                (jnp.asarray(cursor, jnp.int32) % LANES,))
+        in_specs += [pl.BlockSpec((1, active.shape[0]), full),
                      pl.BlockSpec((1, 1), full)]
-        operands += [jnp.asarray(active, jnp.int32).reshape(1, window),
+        operands += [active.reshape(1, -1),
                      jnp.asarray(shift, jnp.float32).reshape(1, 1)]
     outs = pl.pallas_call(
         kern,
@@ -190,9 +216,9 @@ def client_eval_pallas(preds_ext: jnp.ndarray, y_ext: jnp.ndarray,
         out_shape=out_shape,
         interpret=interpret,
     )(*operands)
-    mix, scal, ml = outs[0][0], outs[1], outs[2]
-    grad = outs[3][0] if with_grad else None
-    return mix, scal[0, 0], scal[0, 1], ml[0], grad
+    mix, scal, ml = outs[0][0], outs[1], outs[2][:, 0]
+    grad = outs[3][:, 0] if with_grad else None
+    return mix, scal[0, 0], scal[0, 1], ml, grad
 
 
 def _adapt_refs(kern, with_grad: bool, scheduled: bool):

@@ -2,7 +2,8 @@
 
 ``client_eval`` is the round-body entry point: interpret mode on CPU
 (the kernel body traces to the same XLA ops as the unfused path, so the
-fused round body keeps its trajectories), compiled Pallas on TPU.  The
+fused round body keeps its trajectories), compiled Pallas on TPU, and an
+error on any other backend (``repro.kernels.platform``).  The
 engine (`repro.federated.simulation.make_round_body`) calls it once per
 round behind ``SimConfig.use_fused``; ``extend_stream`` (re-exported
 from ``ref``) prepares the wrap-free stream operands once per jitted
@@ -11,17 +12,13 @@ call.
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from ..platform import interpret_mode
 from .kernel import client_eval_pallas
 from .ref import ClientEvalOut, extend_stream
 
 __all__ = ["client_eval", "extend_stream", "ClientEvalOut"]
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def client_eval(preds_ext: jnp.ndarray, y_ext: jnp.ndarray,
@@ -39,7 +36,7 @@ def client_eval(preds_ext: jnp.ndarray, y_ext: jnp.ndarray,
     the stationary path, which keeps its pre-scenario launch signature.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     mix, ens_sq_mean, ens_norm, model_losses, grad = client_eval_pallas(
         preds_ext, y_ext, cursor, n_t, w, sel, loss_scale=loss_scale,
         window=window, weighting=weighting, with_grad=with_grad,

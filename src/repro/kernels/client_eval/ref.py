@@ -35,7 +35,8 @@ from jax import lax
 from repro.core.numerics import ladder_logsumexp, ladder_matvec, ladder_sum
 
 __all__ = ["ClientEvalOut", "WEIGHTINGS", "mix_weights_ref",
-           "client_eval_ref", "extend_stream"]
+           "client_eval_ref", "extend_stream", "extended_width",
+           "window_frame", "LANES"]
 
 WEIGHTINGS = ("log", "linear", "none")
 
@@ -69,11 +70,30 @@ def mix_weights_ref(w: jnp.ndarray, sel: jnp.ndarray,
     raise ValueError(f"unknown weighting {weighting!r}")
 
 
+LANES = 128   # TPU vector lane width: compiled lane-dim loads start here
+
+
+def window_frame(window: int) -> int:
+    """Width of the lane-aligned frame the compiled kernel loads: the
+    smallest multiple of ``LANES`` that holds a ``window``-wide slice at
+    any offset ``0..LANES-1`` from an aligned start."""
+    return LANES * -(-(window + LANES - 1) // LANES)
+
+
+def extended_width(n_stream: int, window: int) -> int:
+    """Columns of the extended stream: enough that the compiled kernel's
+    frame, which starts at most at ``n_stream - 1`` rounded down to a
+    multiple of ``LANES``, never runs past the end."""
+    return LANES * -(-n_stream // LANES) + window_frame(window)
+
+
 def extend_stream(preds: jnp.ndarray, y: jnp.ndarray, window: int):
     """Wrap-free gather trick: append the first ``window`` columns so the
     round's window ``(cursor + 0..window-1) % n_stream`` is the contiguous
     slice ``[cursor, cursor + window)`` of the extended stream (valid for
-    every ``cursor < n_stream`` as long as ``window <= n_stream``).
+    every ``cursor < n_stream`` as long as ``window <= n_stream``), then
+    zero columns up to ``extended_width`` for the compiled kernel's
+    aligned frame (they are never inside a window).
 
     The extension is loop-invariant — built once per jitted call, *not*
     per round — which is what lets the kernel gather with one dynamic
@@ -83,8 +103,10 @@ def extend_stream(preds: jnp.ndarray, y: jnp.ndarray, window: int):
     if window > n_stream:
         raise ValueError(f"window {window} > stream length {n_stream}; "
                          "the wrap-free extension needs window <= n_stream")
-    return (jnp.concatenate([preds, preds[:, :window]], axis=1),
-            jnp.concatenate([y, y[:window]]))
+    pad = extended_width(n_stream, window) - n_stream - window
+    return (jnp.pad(jnp.concatenate([preds, preds[:, :window]], axis=1),
+                    ((0, 0), (0, pad))),
+            jnp.pad(jnp.concatenate([y, y[:window]]), (0, pad)))
 
 
 def client_eval_ref(preds_ext: jnp.ndarray, y_ext: jnp.ndarray,
@@ -95,8 +117,8 @@ def client_eval_ref(preds_ext: jnp.ndarray, y_ext: jnp.ndarray,
                     shift=None) -> ClientEvalOut:
     """Single-pass jnp reference of the fused round evaluation.
 
-    ``preds_ext``: (K, n_stream + window) extended predictions;
-    ``y_ext``: (n_stream + window,) extended targets (see
+    ``preds_ext``: (K, extended_width) extended predictions;
+    ``y_ext``: (extended_width,) extended targets (see
     ``extend_stream``); ``cursor``/``n_t``: int32 scalars; ``w``/``sel``:
     (K,) weights + transmit mask.  Returns ``ClientEvalOut``.
 

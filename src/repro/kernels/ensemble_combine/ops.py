@@ -4,21 +4,17 @@ mode on CPU; compiled on TPU)."""
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from ..platform import interpret_mode
 from .kernel import ensemble_combine_pallas
 from .ref import mix_weights_ref
 
 __all__ = ["ensemble_combine"]
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def ensemble_combine(preds: jnp.ndarray, log_w: jnp.ndarray,
                      sel: jnp.ndarray) -> jnp.ndarray:
     """preds: (K, N); log_w/sel: (K,) -> ensemble predictions (N,)."""
     mix = mix_weights_ref(log_w, sel)
-    return ensemble_combine_pallas(preds, mix, interpret=not _on_tpu())
+    return ensemble_combine_pallas(preds, mix, interpret=interpret_mode())

@@ -23,7 +23,11 @@ bit-preserving — the same argument the graph builder's batched
 one-hot contractions (exact: one term survives), indices come from
 ``broadcasted_iota`` (1-D ``iota`` does not lower on TPU), and the
 argmax-over-ratio replaces the solo path's ``top_k(x, 1)`` — identical
-selection semantics (both break ties low) on identical float values.
+selection semantics (both break ties low, spelled out in ``_argmax_low``
+because Mosaic's argmax does not, and takes float32 operands only) on
+identical float values.  Two more rules keep the bodies inside what the
+Mosaic compiler accepts: masks ride the loops as int32 (no bool loop
+carries), and a mask is lifted to a column only as int32.
 
 Numerics: float32 throughout; the surrounding float math *calls the
 actual core implementations* (``graph._graph_tables``, ``policy.pmf`` /
@@ -31,7 +35,8 @@ actual core implementations* (``graph._graph_tables``, ``policy.pmf`` /
 ``graph.row_log_weight_sums``) on the same (K,)/(K, K) shapes, so
 interpret mode on CPU traces to the same XLA ops as the unfused server
 and trajectories stay bit-equal (pinned end-to-end on the paper config
-by ``tests/test_server_round.py``).
+by ``tests/test_server_round.py``).  Compiled on a TPU the same bodies
+agree with ``ref`` to float32 rounding, not bit for bit.
 """
 
 from __future__ import annotations
@@ -46,7 +51,19 @@ from repro.core import policy
 from repro.core.graph import _graph_tables, row_log_weight_sums
 from repro.core.numerics import ladder_sum
 
+from ..platform import prepare_mosaic
+
 __all__ = ["server_plan_pallas", "server_update_pallas"]
+
+
+def _argmax_low(x, idx, axis=None):
+    """``jnp.argmax`` with its tie rule spelled out — the lowest index
+    among the maxima.  Mosaic's own argmax breaks ties otherwise, and
+    ties are common here: experts of equal cost tie in eq. (3) while the
+    weights are still equal, and dominating-set gains are small counts.
+    ``idx`` holds each element's index along ``axis``."""
+    top = jnp.max(x, axis=axis, keepdims=axis is not None)
+    return jnp.min(jnp.where(x == top, idx, x.shape[-1]), axis=axis)
 
 
 def _server_plan_kernel(log_w_ref, log_u_ref, lps_ref, costs_ref,
@@ -65,52 +82,56 @@ def _server_plan_kernel(log_w_ref, log_u_ref, lps_ref, costs_ref,
     # --- Algorithm 1: static-trip form of graph._fg's while body -------
     E, s0, W_ROW = _graph_tables(log_w, costs, budget, lps)
 
+    # loop carries are int32, not bool: Mosaic cannot carry i1 vectors
     def append_trip(_, carry):
         mask, cost_sum, s, iters = carry
         den = cost_sum[:, None] + costs[None, :]
-        bad = mask | (den > budget) | (E > (1.0 - s)[:, None])
+        bad = (mask != 0) | (den > budget) | (E > (1.0 - s)[:, None])
         ratio = jnp.where(bad, -1.0, W_ROW / den)
-        d = jnp.argmax(ratio, axis=1)
-        active = jnp.max(ratio, axis=1) >= 0.0
-        upd = (rows[None, :] == d[:, None]) & active[:, None]
-        mask = mask | upd
+        d = _argmax_low(ratio, rows[None, :], axis=1)
+        # kept 2-D: Mosaic cannot lift a 1-D bool vector to a column
+        active = jnp.max(ratio, axis=1, keepdims=True) >= 0.0
+        upd = (rows[None, :] == d[:, None]) & active
+        mask = mask | upd.astype(jnp.int32)
         # one-hot contraction == costs[d] where active (single survivor)
         cost_sum = cost_sum + jnp.sum(
             jnp.where(upd, costs[None, :], 0.0), axis=1)
         s = s + jnp.sum(jnp.where(upd, E, 0.0), axis=1)
         return mask, cost_sum, s, iters + jnp.any(active).astype(jnp.int32)
 
-    adj, _, _, iters = jax.lax.fori_loop(
+    adj_i, _, _, iters = jax.lax.fori_loop(
         0, K - 1, append_trip,
-        (jnp.eye(K, dtype=bool), costs, s0, jnp.int32(0)))
+        (jnp.eye(K, dtype=jnp.int32), costs, s0, jnp.int32(0)))
 
     # --- greedy dominating set: static-trip form of domset._ds ---------
-    adj_i = adj.astype(jnp.int32)
-
     def cover_trip(_, carry):
         dom, unc = carry
         gains = jnp.sum(adj_i * unc[None, :], axis=1)
-        gains = jnp.where(dom, -1, gains)
+        gains = jnp.where(dom != 0, -1, gains)
         covering = jnp.any(unc > 0)
-        onehot = (rows == jnp.argmax(gains)) & covering
+        onehot = ((rows == _argmax_low(gains, rows))
+                  & covering).astype(jnp.int32)
         dom = dom | onehot
-        row = jnp.sum(jnp.where(onehot[:, None], adj_i, 0), axis=0)
+        # integer one-hot row select == adj[argmax] (single survivor)
+        row = jnp.sum(onehot[:, None] * adj_i, axis=0)
         return dom, unc * (1 - row)
 
-    dom, _ = jax.lax.fori_loop(
+    dom_i, _ = jax.lax.fori_loop(
         0, K, cover_trip,
-        (jnp.zeros((K,), dtype=bool), jnp.ones((K,), jnp.int32)))
+        (jnp.zeros((K,), jnp.int32), jnp.ones((K,), jnp.int32)))
+    dom = dom_i != 0
 
     # --- PMF, draw, transmit set, mixture, cost ------------------------
     p = policy.pmf(log_u, dom, xi)
-    drawn = jnp.argmax(gumbel + jnp.log(jnp.maximum(p, 1e-38)))
+    drawn = _argmax_low(gumbel + jnp.log(jnp.maximum(p, 1e-38)), rows)
     # one-hot row select == adj[drawn] (single surviving row)
-    sel = jnp.sum(jnp.where((rows == drawn)[:, None], adj_i, 0), axis=0) > 0
+    sel = jnp.sum((rows == drawn).astype(jnp.int32)[:, None] * adj_i,
+                  axis=0) > 0
     mix = policy.ensemble_mix_weights(log_w, sel)
     round_cost = ladder_sum(jnp.where(sel, costs, 0.0))
 
     adj_ref[...] = adj_i
-    dom_ref[...] = dom.astype(jnp.int32)[None, :]
+    dom_ref[...] = dom_i[None, :]
     p_ref[...] = p.astype(p_ref.dtype)[None, :]
     drawn_ref[...] = drawn.astype(jnp.int32).reshape(1, 1)
     sel_ref[...] = sel.astype(jnp.int32)[None, :]
@@ -170,6 +191,8 @@ def server_plan_pallas(log_w, log_u, log_w_prev_sums, costs, budget,
     round_cost, graph_iters int32)`` — the int32 masks are cast to bool
     by the ``ops`` wrapper.
     """
+    if not interpret:
+        prepare_mosaic()
     K = log_w.shape[0]
     kern = functools.partial(_server_plan_kernel, K=K)
     out_shape = [
@@ -210,6 +233,8 @@ def server_update_pallas(adj, p, sel, drawn, model_losses, ens_loss,
     ``eta``: f32 scalars.  Returns ``(log_w, log_u, log_w_prev_sums)``,
     each (K,) f32.
     """
+    if not interpret:
+        prepare_mosaic()
     K = p.shape[0]
     kern = functools.partial(_server_update_kernel, K=K)
     out_shape = [jax.ShapeDtypeStruct((1, K), jnp.float32)] * 3

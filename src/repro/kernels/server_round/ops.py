@@ -1,7 +1,7 @@
 """Public fused server-round ops: backend dispatch + engine wiring.
 
 ``server_plan`` / ``server_update`` are the two launches (interpret mode
-on CPU, compiled Pallas on TPU), returning the same pytrees as
+on CPU, compiled Pallas on TPU, an error elsewhere), returning the same pytrees as
 ``ref.server_plan_ref`` / ``ref.server_update_ref``.
 
 ``fused_server_round()`` packages them with the
@@ -21,6 +21,7 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from ..platform import interpret_mode
 from .kernel import server_plan_pallas, server_update_pallas
 from .ref import ServerPlanOut, ServerUpdateOut
 
@@ -28,16 +29,12 @@ __all__ = ["server_plan", "server_update", "fused_server_round",
            "FusedServerRound"]
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def server_plan(log_w, log_u, log_w_prev_sums, costs, budget, gumbel, xi,
                 *, interpret: Optional[bool] = None) -> ServerPlanOut:
     """One fused planning launch (see ``ref.server_plan_ref`` for exact
     semantics).  Masks come back as bool."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     adj, dom, p, drawn, sel, mix, cost, iters = server_plan_pallas(
         log_w, log_u, log_w_prev_sums, costs, budget, gumbel, xi,
         interpret=interpret)
@@ -50,7 +47,7 @@ def server_update(adj, p, sel, drawn, model_losses, ens_loss, log_w,
                   interpret: Optional[bool] = None) -> ServerUpdateOut:
     """One fused update launch (see ``ref.server_update_ref``)."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     new_w, new_u, prev = server_update_pallas(
         adj, p, sel, drawn, model_losses, ens_loss, log_w, log_u, eta,
         interpret=interpret)

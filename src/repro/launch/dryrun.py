@@ -120,8 +120,8 @@ def run_one(arch: str, shape: str, mesh_name: str, *, fsdp=None, accum=None,
     meta["devices"] = int(mesh.devices.size)
     meta["reduced"] = bool(reduced)
 
-    from repro.launch.mesh import set_global_mesh, as_shardings
-    set_global_mesh(mesh)
+    from repro.launch.mesh import as_shardings
+    jax.set_mesh(mesh)
     in_specs = as_shardings(mesh, in_specs)
     out_specs = as_shardings(mesh, out_specs)
     # serving donates the KV/SSM caches (argument 1): the updated cache
@@ -152,8 +152,7 @@ def run_one(arch: str, shape: str, mesh_name: str, *, fsdp=None, accum=None,
         rec["memory"] = {"error": str(e)}
 
     try:
-        from repro.launch.compat import cost_analysis_dict
-        ca = cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis()
         rec["cost"] = {k: float(v) for k, v in ca.items()
                        if isinstance(v, (int, float)) and
                        ("flops" in k or "bytes" in k or "utilization" not in k)}

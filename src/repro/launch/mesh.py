@@ -17,7 +17,7 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 __all__ = ["make_production_mesh", "make_sweep_mesh", "data_axes",
-           "MESH_SHAPES", "set_global_mesh", "as_shardings"]
+           "MESH_SHAPES", "as_shardings"]
 
 MESH_SHAPES = {
     "pod": ((16, 16), ("data", "model")),
@@ -51,21 +51,9 @@ def make_sweep_mesh(n_data: int = 1, *, devices=None) -> Mesh:
                 ("sweep", "data"))
 
 
-def set_global_mesh(mesh) -> None:
-    """``jax.set_mesh`` compat: real call on jax>=0.5, context entry on 0.4.x
-    (where ``with mesh:`` is the only way to install a global mesh)."""
-    if hasattr(jax, "set_mesh"):
-        jax.set_mesh(mesh)
-    else:
-        mesh.__enter__()
-
-
 def as_shardings(mesh, specs):
-    """PartitionSpec pytree -> NamedSharding pytree.
-
-    jax 0.4.x rejects bare ``PartitionSpec`` in ``jit`` in/out_shardings;
-    newer jax accepts either, so this is always safe to apply.
-    """
+    """PartitionSpec pytree -> NamedSharding pytree on ``mesh``, for
+    ``jit`` in/out_shardings that must not depend on a global mesh."""
     return jax.tree.map(
         lambda s: NamedSharding(mesh, s) if isinstance(s, PartitionSpec) else s,
         specs, is_leaf=lambda s: isinstance(s, PartitionSpec))

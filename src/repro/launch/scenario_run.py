@@ -145,4 +145,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     raise SystemExit(main())

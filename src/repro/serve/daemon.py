@@ -40,6 +40,9 @@ never stall behind a compile).  Responsibilities, each pinned by
   submits: re-running a simulation is bit-identical), then failed with
   ``WorkerDied``.  A future settles exactly once: ``restore`` drops
   already-settled futures, and settling is first-wins.
+* **one chip per worker** — on a TPU host worker ``i`` sees only chip
+  ``i`` (``worker_env``), and a pool larger than the host's chip count
+  fails at spawn with ``NoChipForWorker``.
 * **graceful drain** — ``drain_and_stop`` rejects new submits, serves
   everything admitted (surviving workers absorb a dead co-worker's
   backlog), shuts every worker down, and only then stops the front
@@ -53,6 +56,7 @@ ready handshake).
 from __future__ import annotations
 
 import argparse
+import glob
 import json
 import os
 import subprocess
@@ -67,7 +71,8 @@ from .queue import RequestQueue, SimFuture, SimRequest
 from .transport import (ConnectionLost, DeadlineExceeded, Overloaded,
                         RpcClient, RpcServer, TransportError, WorkerDied)
 
-__all__ = ["ServeDaemon", "WorkerHandle", "main", "READY_PREFIX"]
+__all__ = ["ServeDaemon", "WorkerHandle", "NoChipForWorker", "worker_env",
+           "host_tpu_chips", "main", "READY_PREFIX"]
 
 READY_PREFIX = "DAEMON-READY "
 
@@ -78,13 +83,16 @@ class WorkerHandle:
     ``worker_id`` is the stable pool slot (assigned by the daemon, not
     the factory) and ``streams`` maps stream name -> the daemon version
     last pushed to THIS worker — the pump's lazy-registration check.
+    ``device`` is the worker's JAX device as its ready line reported it
+    (``{"platform", "kind", "count"}``; ``None`` for stub workers).
     """
 
     def __init__(self, proc: Optional[subprocess.Popen], client: RpcClient,
-                 epoch: int):
+                 epoch: int, device: Optional[dict] = None):
         self.proc = proc
         self.client = client
         self.epoch = epoch
+        self.device = device
         self.worker_id = 0
         self.streams: dict = {}
 
@@ -111,19 +119,106 @@ class WorkerHandle:
                 self.proc.wait(timeout=5.0)
 
 
+class NoChipForWorker(RuntimeError):
+    """The pool has more workers than this host has TPU chips."""
+
+
+# PCI ids of TPU chips (vendor: Google), as jax's hardware_utils lists
+# them — read from sysfs here so the daemon never imports jax
+_GOOGLE_PCI_VENDOR = "0x1ae0"
+_TPU_PCI_DEVICES = frozenset({"0x0027", "0x0056", "0x005e", "0x0062",
+                              "0x0063", "0x006f", "0x0076"})
+_TPU_PROCESS_PORT = 8476      # libtpu's default; worker i takes 8476 + i
+
+
+def host_tpu_chips(sysfs: str = "/sys/bus/pci/devices",
+                   dev: str = "/dev") -> int:
+    """Number of TPU chips this host lets its processes open.
+
+    A chip on the PCI bus counts when its device node exists:
+    ``/dev/vfio/<iommu group>`` (v5e and later) or ``/dev/accel<i>``.  A
+    machine may attach more chips than it exposes — a one-chip slice of
+    a four-chip host shows four on the bus and one node — and libtpu
+    numbers only the chips it can open, ``0..n-1``, for
+    ``TPU_VISIBLE_CHIPS``."""
+    n = i = 0
+    for path in sorted(glob.glob(os.path.join(sysfs, "*"))):
+        try:
+            with open(os.path.join(path, "vendor")) as f:
+                vendor = f.read().strip()
+            with open(os.path.join(path, "device")) as f:
+                device = f.read().strip()
+        except OSError:
+            continue
+        if vendor != _GOOGLE_PCI_VENDOR or device not in _TPU_PCI_DEVICES:
+            continue
+        group = os.path.join(path, "iommu_group")
+        node = (os.path.join(dev, "vfio",
+                             os.path.basename(os.readlink(group)))
+                if os.path.islink(group) else os.path.join(dev, f"accel{i}"))
+        n += os.path.exists(node)
+        i += 1
+    return n
+
+
+def worker_env(worker_id: int, environ=None, chips=None) -> dict:
+    """The environment of pool worker ``worker_id``.
+
+    A chip belongs to one process: on a TPU host every worker sees
+    exactly one chip through libtpu's visibility variables — worker
+    ``i`` takes the ``i``-th of the chips the daemon may use (the
+    parent's ``TPU_VISIBLE_CHIPS`` when set, else every chip of the
+    host) — and is held to the TPU platform, so a worker that cannot get
+    its chip fails instead of falling back to the CPU.  A pool larger
+    than that chip list raises ``NoChipForWorker`` naming the missing
+    chip, instead of starting a worker that waits on libtpu's lock.  A
+    host without TPU chips, or a parent that pins JAX to other platforms
+    (``JAX_PLATFORMS=cpu``, as the tests do), passes its environment
+    through unchanged.  ``chips`` overrides the host's chip count
+    (``host_tpu_chips()``)."""
+    env = dict(os.environ if environ is None else environ)
+    platforms = env.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return env
+    visible = env.get("TPU_VISIBLE_CHIPS", "")
+    if visible:
+        ids = [c.strip() for c in visible.split(",") if c.strip()]
+    else:
+        ids = [str(c) for c in range(host_tpu_chips() if chips is None
+                                     else chips)]
+    if not ids:
+        return env
+    if worker_id >= len(ids):
+        raise NoChipForWorker(
+            f"worker {worker_id} needs TPU chip #{worker_id}, but only "
+            f"{len(ids)} chip(s) are visible here ({','.join(ids)}): run "
+            f"at most {len(ids)} worker(s)")
+    port = _TPU_PROCESS_PORT + worker_id
+    env.update(JAX_PLATFORMS=platforms or "tpu",
+               TPU_VISIBLE_CHIPS=ids[worker_id],
+               TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1",
+               TPU_PROCESS_BOUNDS="1,1,1",
+               TPU_PROCESS_PORT=str(port),
+               TPU_PROCESS_ADDRESSES=f"localhost:{port}")
+    return env
+
+
 def _spawn_worker_subprocess(worker_args: dict, epoch: int) -> WorkerHandle:
     """Default worker factory: ``python -m repro.serve.worker`` with an
-    ephemeral port, handshaken via the WORKER-READY stdout line (slow on
-    purpose — the worker imports jax)."""
+    ephemeral port, pinned to its own chip (``worker_env``), handshaken
+    via the WORKER-READY stdout line (slow on purpose — the worker
+    imports jax)."""
+    worker_id = worker_args.get("worker_id", 0)
+    env = worker_env(worker_id)
     cmd = [sys.executable, "-m", "repro.serve.worker", "--port", "0",
            "--max-batch", str(worker_args.get("max_batch", 16)),
            "--max-wait-ms", str(worker_args.get("max_wait_ms", 2.0)),
-           "--worker-id", str(worker_args.get("worker_id", 0))]
+           "--worker-id", str(worker_id)]
     proc = subprocess.Popen(cmd, stdout=subprocess.PIPE, stderr=None,
-                            env=dict(os.environ), text=True)
+                            env=env, text=True)
     from .worker import READY_PREFIX as WORKER_READY
     deadline = time.monotonic() + worker_args.get("spawn_timeout_s", 120.0)
-    addr = None
+    addr = info = None
     while time.monotonic() < deadline:
         line = proc.stdout.readline()
         if not line:
@@ -136,7 +231,7 @@ def _spawn_worker_subprocess(worker_args: dict, epoch: int) -> WorkerHandle:
         proc.kill()
         raise WorkerDied("worker failed to announce readiness")
     client = RpcClient(addr, connect_timeout=10.0)
-    return WorkerHandle(proc, client, epoch)
+    return WorkerHandle(proc, client, epoch, device=info.get("device"))
 
 
 class ServeDaemon:
@@ -201,8 +296,16 @@ class ServeDaemon:
         return self._rpc.addr
 
     def start(self) -> "ServeDaemon":
-        for wid in range(self.workers):
-            self._spawn_worker(wid)
+        try:
+            for wid in range(self.workers):
+                self._spawn_worker(wid)
+        except BaseException:
+            # a failed spawn (no chip for it, no readiness) must not
+            # leave the workers already started holding their chips
+            for handle in self._pool.values():
+                if handle is not None:
+                    handle.kill()
+            raise
         self._rpc = RpcServer({
             "ping": lambda p, c: {"pong": True},
             "submit": self._h_submit,
@@ -632,6 +735,7 @@ class ServeDaemon:
                     "alive": h is not None and h.alive,
                     "pid": h.pid if h else None,
                     "epoch": h.epoch if h else None,
+                    "device": h.device if h else None,
                     "restarts": self._wrestarts[wid],
                     "inflight": len(self._winflight[wid]),
                     "backlog": len(self._backlog[wid]),

@@ -134,6 +134,12 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     obs.set_service(f"worker{args.worker_id}")
+    # take the device before announcing readiness: a worker that cannot
+    # get its chip fails the spawn instead of the first request
+    import jax
+    dev = jax.devices()
+    device = {"platform": dev[0].platform, "kind": dev[0].device_kind,
+              "count": len(dev)}
     from .server import SimServer
     server = SimServer(max_batch=args.max_batch,
                        max_wait_ms=args.max_wait_ms, poll_s=args.poll_s)
@@ -158,7 +164,8 @@ def main(argv=None) -> int:
     host, port = rpc.addr
     print(READY_PREFIX + json.dumps({"host": host, "port": port,
                                      "pid": __import__("os").getpid(),
-                                     "worker_id": args.worker_id}),
+                                     "worker_id": args.worker_id,
+                                     "device": device}),
           flush=True)
     stop.wait()
     # graceful drain: no new requests (listener down), everything already
@@ -169,4 +176,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     sys.exit(main())

@@ -25,8 +25,7 @@ def test_hloparse_counts_scan_trips():
     res = analyze_hlo(compiled.as_text())
     expected = 2 * b * n * n * L
     assert res["dot_flops"] == expected, (res["dot_flops"], expected)
-    from repro.launch.compat import cost_analysis_dict
-    reported = cost_analysis_dict(compiled).get("flops", 0)
+    reported = compiled.cost_analysis().get("flops", 0)
     assert reported < expected  # the very bug the parser fixes
 
 

@@ -62,10 +62,10 @@ from repro.models import get_config, model
 from repro.optim import AdamWConfig, make_train_step, init_train_state
 from jax.sharding import PartitionSpec as P
 
-from repro.launch.mesh import set_global_mesh, as_shardings
+from repro.launch.mesh import as_shardings
 
 mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
-set_global_mesh(mesh)
+jax.set_mesh(mesh)
 
 cfg = get_config("qwen3-1.7b").reduced(n_layers=2, vocab_size=2048,
                                        d_model=256, n_heads=4, n_kv_heads=2)
@@ -100,10 +100,9 @@ lowered = jax.jit(step, in_shardings=as_shardings(mesh, (sspecs, bspecs)),
                   out_shardings=as_shardings(mesh, (sspecs, None))
                   ).lower(state_shapes, batch)
 compiled = lowered.compile()
-from repro.launch.compat import cost_analysis_dict
 
 ma = compiled.memory_analysis()
-ca = cost_analysis_dict(compiled)
+ca = compiled.cost_analysis()
 print(json.dumps({
     "ok": True,
     "devices": jax.device_count(),
