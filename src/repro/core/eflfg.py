@@ -137,10 +137,12 @@ def make_eflfg_scan_body(loss_fn, costs: jnp.ndarray, budget: jnp.ndarray,
         else:
             budget_t = budget * x.budget_scale
             sched = (x.active, x.label_shift)
-        plan = plan_fn(state, kdraw, costs, budget_t, xi)
+        with jax.named_scope("round.draw"):
+            plan = plan_fn(state, kdraw, costs, budget_t, xi)
         model_losses, ens_loss, loss_carry, out = loss_fn(plan, loss_carry,
                                                           sched)
-        state = update_fn(state, plan, model_losses, ens_loss, eta)
+        with jax.named_scope("round.update"):
+            state = update_fn(state, plan, model_losses, ens_loss, eta)
         return (state, key, loss_carry), out
 
     return body

@@ -110,10 +110,12 @@ def make_fedboost_scan_body(grad_fn, costs: jnp.ndarray, budget: jnp.ndarray,
         else:
             budget_t = budget * x.budget_scale
             sched = (x.active, x.label_shift)
-        sel, pi, mix, cost = fedboost_plan(state, ksub, costs, budget_t)
+        with jax.named_scope("round.draw"):
+            sel, pi, mix, cost = fedboost_plan(state, ksub, costs, budget_t)
         grad, loss_carry, out = grad_fn((sel, pi, mix, cost), loss_carry,
                                         sched)
-        state = fedboost_update(state, sel, pi, grad, lr)
+        with jax.named_scope("round.update"):
+            state = fedboost_update(state, sel, pi, grad, lr)
         return (state, key, loss_carry), out
 
     return body

@@ -237,7 +237,8 @@ def feedback_graph(log_w: jnp.ndarray, costs: jnp.ndarray, budget: jnp.ndarray,
         disables the weight constraint exactly as the paper's t=1 round
         (where no previous neighborhood exists).
     """
-    mask, iters = _fg(log_w, costs, jnp.asarray(budget), log_w_prev_sums)
+    with jax.named_scope("round.graph"):
+        mask, iters = _fg(log_w, costs, jnp.asarray(budget), log_w_prev_sums)
     return (mask, iters) if with_iters else mask
 
 

@@ -51,6 +51,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core import RegretTracker
 from . import sweep_sharding
 from .simulation import SimConfig, SimResult, eval_window, make_round_body
@@ -58,6 +59,10 @@ from .simulation import SimConfig, SimResult, eval_window, make_round_body
 __all__ = ["run_simulation_scan", "run_batch", "batch_dispatch_plan",
            "batch_buckets", "run_sweep", "run_sweep_sharded", "SweepResult"]
 
+
+# A call's root span is counted under ``<root>.compiled`` when JAX
+# compiled inside it, and kept out of the steady-state span histograms.
+obs.watch_compiles(jax.monitoring)
 
 # Compiled scans are cached per configuration: the stream data, PRNG key
 # and budget are jit *arguments*, so re-running (other seeds, other
@@ -277,6 +282,18 @@ def _get_scan(algo: str, T: int, cfg: SimConfig, sweep: str = "",
     return fn
 
 
+def _run_program(fn, *args):
+    """Run a jitted program and bring its outputs to the host: the
+    enqueue (``engine.launch``), the wait for the device
+    (``engine.device_wait``) and the copy (``engine.fetch``)."""
+    with obs.span("engine.launch"):
+        outs = fn(*args)
+    with obs.span("engine.device_wait"):
+        jax.block_until_ready(outs)
+    with obs.span("engine.fetch"):
+        return jax.tree.map(np.asarray, outs)
+
+
 def _sweep_outs(outs):
     outs = dict(outs)
     outs.pop("ml_norm")              # (T, K) per config: sweep keeps it lean
@@ -408,9 +425,6 @@ def run_batch(algo: str, preds, y, costs, T: int, cfg: SimConfig,
     tests/test_serve.py; the full equality map is
     docs/serving.md#determinism.
     """
-    preds = jnp.asarray(preds, jnp.float32)
-    y = jnp.asarray(y, jnp.float32)
-    costs = jnp.asarray(costs, jnp.float32)
     seeds = list(seeds)
     n = len(seeds)
     if budgets is None:
@@ -419,34 +433,33 @@ def run_batch(algo: str, preds, y, costs, T: int, cfg: SimConfig,
     if len(budgets) != n:
         raise ValueError(f"run_batch: {n} seeds but {len(budgets)} budgets "
                          "— the batch axis is flat (one pair per lane)")
-    keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
-    budgets_j = jnp.asarray(budgets, jnp.float32)
-    sched, scale = _lane_schedules(scenario, T, cfg, n)
-    scheduled = sched is not None
-
-    sharded, mesh = batch_dispatch_plan(cfg, n, mesh)
-    if sharded:
-        n_sweep, _ = sweep_sharding.mesh_axes(mesh)
-        pk, pb = sweep_sharding.pad_configs(keys, budgets_j, n_sweep)
-        fn = _get_sharded_flat(algo, T, cfg, mesh, scheduled=scheduled)
-        if scheduled:
-            ps = sweep_sharding.pad_lane_tree(sched, n_sweep)
-            outs = fn(preds, y, costs, pk, pb, ps)
-        else:
-            outs = fn(preds, y, costs, pk, pb)
-        outs = jax.tree.map(lambda a: np.asarray(a)[:n], outs)
-    else:
-        fn = _get_scan(algo, T, cfg, sweep="flat", scheduled=scheduled)
-
-        def dispatch(ks, bs, ss=None):
-            return jax.tree.map(
-                np.asarray,
-                fn(preds, y, costs, ks, bs, ss) if scheduled
-                else fn(preds, y, costs, ks, bs))
-
-        buckets = batch_buckets(algo, budgets)
-        if buckets is None:
-            outs = dispatch(keys, budgets_j, sched)
+    with obs.span("engine.run_batch", items=n):
+        with obs.span("engine.prepare"):
+            preds = jnp.asarray(preds, jnp.float32)
+            y = jnp.asarray(y, jnp.float32)
+            costs = jnp.asarray(costs, jnp.float32)
+            keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
+            budgets_j = jnp.asarray(budgets, jnp.float32)
+            sched, scale = _lane_schedules(scenario, T, cfg, n)
+            scheduled = sched is not None
+            sharded, mesh = batch_dispatch_plan(cfg, n, mesh)
+            if sharded:
+                n_sweep, _ = sweep_sharding.mesh_axes(mesh)
+                pk, pb = sweep_sharding.pad_configs(keys, budgets_j, n_sweep)
+                fn = _get_sharded_flat(algo, T, cfg, mesh,
+                                       scheduled=scheduled)
+                args = (preds, y, costs, pk, pb)
+                if scheduled:
+                    args += (sweep_sharding.pad_lane_tree(sched, n_sweep),)
+            else:
+                fn = _get_scan(algo, T, cfg, sweep="flat",
+                               scheduled=scheduled)
+                buckets = batch_buckets(algo, budgets)
+        if sharded:
+            outs = jax.tree.map(lambda a: a[:n], _run_program(fn, *args))
+        elif buckets is None:
+            outs = _run_program(fn, preds, y, costs, keys, budgets_j,
+                                *(() if sched is None else (sched,)))
         else:
             # budget-compacted dispatch: one flat program per budget
             # bucket, so each bucket's graph loop runs only ITS max trip
@@ -457,24 +470,28 @@ def run_batch(algo: str, preds, y, costs, T: int, cfg: SimConfig,
             # each bucket carries exactly its lanes' rows.
             outs = None
             for idx in buckets:
-                sel = jnp.asarray(idx)
-                o = dispatch(keys[sel], budgets_j[sel],
-                             None if sched is None else
-                             jax.tree.map(lambda a: a[sel], sched))
-                if outs is None:
-                    outs = {k: np.empty((n,) + v.shape[1:], v.dtype)
-                            for k, v in o.items()}
-                for k, v in o.items():
-                    outs[k][idx] = v
-    if scale is None:
-        thresh = [budgets[i] for i in range(n)]
-    elif scale.ndim == 1:           # one shared scenario: (T,) factors
-        thresh = [budgets[i] * scale for i in range(n)]
-    else:                           # per-lane scenarios: (n, T) factors
-        thresh = [budgets[i] * scale[i] for i in range(n)]
-    return [_to_result(jax.tree.map(lambda a: a[i], outs), T,
-                       thresh[i], algo)
-            for i in range(n)]
+                with obs.span("engine.prepare"):
+                    sel = jnp.asarray(idx)
+                    args = (preds, y, costs, keys[sel], budgets_j[sel])
+                    if sched is not None:
+                        args += (jax.tree.map(lambda a: a[sel], sched),)
+                o = _run_program(fn, *args)
+                with obs.span("engine.unpack"):
+                    if outs is None:
+                        outs = {k: np.empty((n,) + v.shape[1:], v.dtype)
+                                for k, v in o.items()}
+                    for k, v in o.items():
+                        outs[k][idx] = v
+        with obs.span("engine.unpack"):
+            if scale is None:
+                thresh = [budgets[i] for i in range(n)]
+            elif scale.ndim == 1:       # one shared scenario: (T,) factors
+                thresh = [budgets[i] * scale for i in range(n)]
+            else:                       # per-lane scenarios: (n, T) factors
+                thresh = [budgets[i] * scale[i] for i in range(n)]
+            return [_to_result(jax.tree.map(lambda a: a[i], outs), T,
+                               thresh[i], algo)
+                    for i in range(n)]
 
 
 def batch_dispatch_plan(cfg: SimConfig, n: int, mesh=None):
@@ -739,40 +756,43 @@ def run_sweep_sharded(algo: str, preds, y, costs, T: int, cfg: SimConfig,
     default path is the fused-vs-unfused kernel choice, not reduction
     order.  Both pinned by tests/test_sweep_sharding.py.
     """
-    preds = jnp.asarray(preds, jnp.float32)
-    y = jnp.asarray(y, jnp.float32)
-    costs = jnp.asarray(costs, jnp.float32)
     seeds = list(seeds)
-    keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
-    sched, scale = _lane_schedules(scenario, T, cfg, len(seeds))
-    scheduled = sched is not None
-    if mesh is None:
-        mesh = sweep_sharding.default_sweep_mesh()
-    n_sweep, _ = sweep_sharding.mesh_axes(mesh)
-    flat_keys, flat_budgets, grid_shape, budgets_arr = _flatten_configs(
-        keys, budgets, cfg.budget)
-    n_cfg = flat_keys.shape[0]
-    flat_keys, flat_budgets = sweep_sharding.pad_configs(
-        flat_keys, flat_budgets, n_sweep)
-    fn = _get_sharded_sweep(algo, T, cfg, mesh, scheduled=scheduled)
-    if scheduled:
-        if grid_shape is not None:
-            # the flat config axis is budgets-outermost: tile each seed
-            # lane's schedule rows once per budget row, matching
-            # _flatten_configs' layout
-            sched = jax.tree.map(
-                lambda a: jnp.tile(a, (grid_shape[0],)
-                                   + (1,) * (a.ndim - 1)), sched)
-        sched = sweep_sharding.pad_lane_tree(sched, n_sweep)
-        outs = fn(preds, y, costs, flat_keys, flat_budgets, sched)
-    else:
-        outs = fn(preds, y, costs, flat_keys, flat_budgets)
-    outs = jax.tree.map(lambda a: np.asarray(a)[:n_cfg], outs)
-    if grid_shape is not None:
-        outs = jax.tree.map(
-            lambda a: a.reshape(grid_shape + a.shape[1:]), outs)
-    return SweepResult(outs, seeds, budgets_arr, T, sharded=True,
-                       budget_scale=scale)
+    n_cfg = len(seeds) * (1 if budgets is None else len(budgets))
+    with obs.span("engine.run_sweep", items=n_cfg):
+        with obs.span("engine.prepare"):
+            preds = jnp.asarray(preds, jnp.float32)
+            y = jnp.asarray(y, jnp.float32)
+            costs = jnp.asarray(costs, jnp.float32)
+            keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
+            sched, scale = _lane_schedules(scenario, T, cfg, len(seeds))
+            scheduled = sched is not None
+            if mesh is None:
+                mesh = sweep_sharding.default_sweep_mesh()
+            n_sweep, _ = sweep_sharding.mesh_axes(mesh)
+            flat_keys, flat_budgets, grid_shape, budgets_arr = \
+                _flatten_configs(keys, budgets, cfg.budget)
+            n_cfg = flat_keys.shape[0]
+            flat_keys, flat_budgets = sweep_sharding.pad_configs(
+                flat_keys, flat_budgets, n_sweep)
+            fn = _get_sharded_sweep(algo, T, cfg, mesh, scheduled=scheduled)
+            args = (preds, y, costs, flat_keys, flat_budgets)
+            if scheduled:
+                if grid_shape is not None:
+                    # the flat config axis is budgets-outermost: tile
+                    # each seed lane's schedule rows once per budget row,
+                    # matching _flatten_configs' layout
+                    sched = jax.tree.map(
+                        lambda a: jnp.tile(a, (grid_shape[0],)
+                                           + (1,) * (a.ndim - 1)), sched)
+                args += (sweep_sharding.pad_lane_tree(sched, n_sweep),)
+        outs = _run_program(fn, *args)
+        with obs.span("engine.unpack"):
+            outs = jax.tree.map(lambda a: a[:n_cfg], outs)
+            if grid_shape is not None:
+                outs = jax.tree.map(
+                    lambda a: a.reshape(grid_shape + a.shape[1:]), outs)
+            return SweepResult(outs, seeds, budgets_arr, T, sharded=True,
+                               budget_scale=scale)
 
 
 def _dispatch_sharded(cfg: SimConfig, n_cfg: int) -> bool:
@@ -824,21 +844,28 @@ def run_sweep(algo: str, preds, y, costs, T: int, cfg: SimConfig,
     if mesh is not None or _dispatch_sharded(cfg, n_cfg):
         return run_sweep_sharded(algo, preds, y, costs, T, cfg, seeds,
                                  budgets, mesh=mesh, scenario=scenario)
-    preds = jnp.asarray(preds, jnp.float32)
-    y = jnp.asarray(y, jnp.float32)
-    costs = jnp.asarray(costs, jnp.float32)
-    keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
-    sched, scale = _lane_schedules(scenario, T, cfg, len(seeds))
-    scheduled = sched is not None
-    if budgets is None:
-        fn = _get_scan(algo, T, cfg, sweep="seeds", scheduled=scheduled)
-        args = (preds, y, costs, keys, jnp.float32(cfg.budget))
-        budgets_arr = np.float64(cfg.budget)
-    else:
-        budgets_j = jnp.asarray(list(budgets), jnp.float32)
-        fn = _get_scan(algo, T, cfg, sweep="grid", scheduled=scheduled)
-        args = (preds, y, costs, keys, budgets_j)
-        budgets_arr = np.asarray(budgets_j)
-    outs = fn(*args, sched) if scheduled else fn(*args)
-    outs = jax.tree.map(np.asarray, outs)
-    return SweepResult(outs, seeds, budgets_arr, T, budget_scale=scale)
+    with obs.span("engine.run_sweep", items=n_cfg):
+        with obs.span("engine.prepare"):
+            preds = jnp.asarray(preds, jnp.float32)
+            y = jnp.asarray(y, jnp.float32)
+            costs = jnp.asarray(costs, jnp.float32)
+            keys = jnp.stack([jax.random.PRNGKey(int(s)) for s in seeds])
+            sched, scale = _lane_schedules(scenario, T, cfg, len(seeds))
+            scheduled = sched is not None
+            if budgets is None:
+                fn = _get_scan(algo, T, cfg, sweep="seeds",
+                               scheduled=scheduled)
+                args = (preds, y, costs, keys, jnp.float32(cfg.budget))
+                budgets_arr = np.float64(cfg.budget)
+            else:
+                budgets_j = jnp.asarray(budgets, jnp.float32)
+                fn = _get_scan(algo, T, cfg, sweep="grid",
+                               scheduled=scheduled)
+                args = (preds, y, costs, keys, budgets_j)
+                budgets_arr = np.asarray(budgets_j)
+            if scheduled:
+                args += (sched,)
+        outs = _run_program(fn, *args)
+        with obs.span("engine.unpack"):
+            return SweepResult(outs, seeds, budgets_arr, T,
+                               budget_scale=scale)

@@ -271,6 +271,7 @@ def _eflfg_loss_fn(evaluate, cfg, n_stream):
     apart structurally.  The cursor always advances by ``n_t``: stream
     time passes whether or not a masked client reports.
     """
+    @jax.named_scope("round.eval")
     def loss_fn(plan, loss_carry, sched=None):
         cursor, racc = loss_carry
         sel_size = jnp.sum(plan.sel).astype(jnp.int32)
@@ -291,6 +292,7 @@ def _fedboost_grad_fn(evaluate, cfg, n_stream):
     """Client-side gradient closure for the FedBoost round body (same
     ``evaluate`` contract as ``_eflfg_loss_fn``, with the gradient slot
     populated; ``graph_iters`` is zero — FedBoost builds no graph)."""
+    @jax.named_scope("round.eval")
     def grad_fn(plan, loss_carry, sched=None):
         sel, _pi, _mix, cost = plan
         cursor, racc = loss_carry

@@ -17,7 +17,7 @@ from __future__ import annotations
 from .metrics import MetricsRegistry
 
 __all__ = ["DAEMON_COUNTERS", "SERVER_COUNTERS", "QUEUE_INSTRUMENTS",
-           "register_counters"]
+           "SPANS", "register_counters"]
 
 # ServeDaemon request-lifecycle counters (previously the ad-hoc
 # ``ServeDaemon.counters`` dict).  Order is the legacy JSON key order.
@@ -38,7 +38,7 @@ SERVER_COUNTERS = {
     "served": "request lanes completed",
     "failed": "request lanes failed",
     "batches": "buckets dispatched",
-    "batched_lanes": "real (non-padding) lanes in batched buckets",
+    "batched_lanes": "lanes dispatched in batched buckets, padding included",
     "padded_lanes": "padding lanes traced-and-dropped",
     "exact_requests": "lanes served on the exact (solo-program) path",
     "sharded_batches": "buckets dispatched through run_sweep_sharded",
@@ -53,6 +53,51 @@ QUEUE_INSTRUMENTS = {
     "depth": ("gauge", "requests currently queued"),
     "oldest_age_s": ("gauge", "age of the oldest queued request"),
     "wait_s": ("histogram", "queue residency, observed at claim time"),
+}
+
+
+# Process spans (``repro.obs.span``), recorded into ``repro.obs.SPANS``:
+# one duration histogram per span path (root first, ``/``-joined), the
+# work counts (``<path>.items``), the compiled-tree counts
+# (``<root>.compiled``) and the compile time.
+_ENGINE_CALL = {
+    "engine.prepare": "host inputs up to the jitted call: arrays, the "
+                      "per-seed key loop, schedules, program lookup",
+    "engine.launch": "the jitted call's enqueue (where compiles land)",
+    "engine.device_wait": "blocked until the call's outputs are ready",
+    "engine.fetch": "device-to-host copy of the per-round outputs",
+    "engine.unpack": "building the SweepResult / per-lane SimResults",
+}
+SPANS = {
+    "engine.run_sweep": ("span", "one run_sweep / run_sweep_sharded call"),
+    **{f"engine.run_sweep/{k}": ("span", v) for k, v in _ENGINE_CALL.items()},
+    "engine.run_sweep.items": ("counter", "lanes (seeds x budgets) swept"),
+    "engine.run_sweep.compiled": ("counter",
+                                  "run_sweep calls in which JAX compiled"),
+    "engine.run_batch": ("span", "one run_batch call outside a dispatch"),
+    **{f"engine.run_batch/{k}": ("span", v) for k, v in _ENGINE_CALL.items()},
+    "engine.run_batch.items": ("counter", "lanes run, padding included"),
+    "engine.run_batch.compiled": ("counter",
+                                  "run_batch calls in which JAX compiled"),
+    "serve.dispatch": ("span", "one bucket on the dispatch thread, from "
+                               "resolution to its futures settled"),
+    "serve.dispatch.items": ("counter", "requests dispatched"),
+    "serve.dispatch.compiled": ("counter",
+                                "buckets in which JAX compiled"),
+    "serve.dispatch/server.resolve": ("span", "stream, config and per-lane "
+                                      "budget / schedule resolution"),
+    "serve.dispatch/engine.run_batch": ("span", "the bucket's run_batch"),
+    **{f"serve.dispatch/engine.run_batch/{k}": ("span", v)
+       for k, v in _ENGINE_CALL.items()},
+    "serve.dispatch/engine.run_batch.items": ("counter",
+                                              "lanes, padding included"),
+    "serve.dispatch/server.settle": ("span", "set_result / set_exception "
+                                     "and the done-callbacks they run"),
+    "server.plan": ("span", "planning a drain into buckets"),
+    "server.wait_work": ("span", "a drain blocked on an empty queue"),
+    "server.coalesce": ("span", "the batcher's max_wait_ms linger"),
+    "jax.compile_s": ("histogram", "JAX's jaxpr-trace and backend-compile "
+                                   "events inside span trees (traces nest)"),
 }
 
 

@@ -10,8 +10,7 @@ never-imports-jax property.  Three design rules:
   per-worker snapshots without any process ever storing samples.
 * **Snapshots are plain JSON-able dicts** — they ride the existing
   ``stats`` RPC unchanged, merge anywhere (:meth:`MetricsRegistry.merge`),
-  and render to JSON (:func:`to_json`) or Prometheus text exposition
-  (:func:`render_prometheus`).
+  and render to Prometheus text exposition (:func:`render_prometheus`).
 * **Observe-only.**  Instruments record counts and seconds; they never
   hold references to engine results.
 """
@@ -19,7 +18,6 @@ never-imports-jax property.  Three design rules:
 from __future__ import annotations
 
 import bisect
-import json
 import math
 import re
 import threading
@@ -27,7 +25,7 @@ from typing import Callable, Dict, Iterable, Optional, Sequence, Tuple
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "log_bounds", "quantile", "to_json", "render_prometheus",
+    "log_bounds", "quantile", "render_prometheus",
 ]
 
 
@@ -267,11 +265,6 @@ class MetricsRegistry:
                     acc[key] = (b if a is None else
                                 a if b is None else pick(a, b))
         return out
-
-
-def to_json(snapshot: dict, indent: Optional[int] = None) -> str:
-    """Deterministic JSON rendering of a snapshot (sorted keys)."""
-    return json.dumps(snapshot, sort_keys=True, indent=indent)
 
 
 _PROM_NAME = re.compile(r"[^a-zA-Z0-9_:]")

@@ -5,11 +5,11 @@ engine result ever flows through it, so turning it off must change
 *nothing* but the telemetry.  The switch exists for exactly two
 consumers — the pinned bit-equality test (instrumented run == plain
 run) and the ``serve.obs_overhead`` bench cell (enabled vs disabled
-timing on the same traffic) — and it gates the *per-request* work:
-span recording, trace-context minting, and latency-histogram
-observations.  Counters and gauges that back ``status()``/``stats()``
-stay live either way; they replaced the old ad-hoc dicts and the
-control plane reads them.
+timing on the same traffic) — and it gates the *per-request* work
+(span recording, trace-context minting, latency-histogram
+observations) and the process spans (``repro.obs.span``).  Counters
+and gauges that back ``status()``/``stats()`` stay live either way;
+they replaced the old ad-hoc dicts and the control plane reads them.
 
 The initial state comes from ``REPRO_OBS`` (default on; ``0``,
 ``false``, ``no``, ``off`` disable), so a subprocess fleet inherits

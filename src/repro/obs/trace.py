@@ -19,20 +19,33 @@ Recording is gated on the global switch (``repro.obs.state``): when
 disabled, :func:`mint` returns ``None`` and recorders no-op — the
 hook that makes the bit-equality pin and the overhead bench's
 "uninstrumented" arm honest.
+
+*Process spans* (:func:`span`) time the program's own steps — an
+engine call and its phases, the serve dispatch thread's waits and
+buckets — on every call, traced request or not.  Each is a
+``jax.profiler.TraceAnnotation`` when JAX is loaded, so it lands on
+the profiler's host plane on the device ops' clock, and its duration
+goes into one process-wide registry (:data:`SPANS`), keyed by its path
+from its root span.  A root tree in which JAX compiled is counted
+under ``<root>.compiled`` instead of being observed, so the
+histograms hold steady-state steps only (:func:`watch_compiles`).
 """
 
 from __future__ import annotations
 
 import collections
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
 
 from . import clock, state
+from .metrics import MetricsRegistry, log_bounds
 
-__all__ = ["mint", "child", "Tracer", "TRACER", "set_service",
-           "to_perfetto", "DEFAULT_CAPACITY"]
+__all__ = ["mint", "Tracer", "TRACER", "set_service", "to_perfetto",
+           "DEFAULT_CAPACITY", "span", "SPANS", "SPAN_BOUNDS",
+           "watch_compiles"]
 
 DEFAULT_CAPACITY = 4096
 
@@ -53,13 +66,6 @@ def mint(parent: Optional[dict] = None) -> Optional[dict]:
         return {"trace_id": str(parent["trace_id"]),
                 "span_id": _hex_id(4)}
     return {"trace_id": _hex_id(8), "span_id": _hex_id(4)}
-
-
-def child(trace: Optional[dict]) -> Optional[dict]:
-    """A child context of ``trace`` (same trace id, new span id)."""
-    if not trace:
-        return None
-    return mint(parent=trace)
 
 
 class Tracer:
@@ -193,3 +199,159 @@ def to_perfetto(spans: List[dict]) -> dict:
     meta = [{"name": "process_name", "ph": "M", "pid": p,
              "args": {"name": svc}} for svc, p in pids.items()]
     return {"traceEvents": meta + events, "displayTimeUnit": "ms"}
+
+
+# ---------------------------------------------------------------------------
+# Process spans
+# ---------------------------------------------------------------------------
+
+# Every process span's duration, keyed by its path from its root span
+# (``serve.dispatch/engine.run_batch/engine.device_wait``); work counts
+# under ``<path>.items``, compiled root trees under ``<root>.compiled``.
+SPANS = MetricsRegistry()
+SPAN_BOUNDS = log_bounds(1e-6, 1e3, 10)      # 1 µs .. 17 min, 10 a decade
+
+# The JAX events that mean "this call compiled": the ones the
+# benchmark harness counts as compiles in its window.
+COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                  "/jax/core/compile/backend_compile_duration")
+
+_local = threading.local()
+_watch_lock = threading.Lock()
+_watching = False
+
+
+def _open_spans() -> list:
+    stack = getattr(_local, "stack", None)
+    if stack is None:
+        stack = _local.stack = []
+    return stack
+
+
+class Span:
+    """One open process span; use through :func:`span`.
+
+    ``parent`` is the innermost span open on this thread when it
+    entered, ``root`` the outermost.  A root buffers its tree's
+    observations and flushes them when it closes — into the histograms,
+    or, if JAX compiled anywhere in the tree, into one
+    ``<root>.compiled`` count."""
+
+    __slots__ = ("name", "path", "parent", "root", "t0", "t1", "items",
+                 "traces", "attrs", "compiled", "_buf", "_ann")
+
+    def __init__(self, name: str, trace=None, items: int = 0):
+        self.name = name
+        self.items = items
+        if isinstance(trace, dict):
+            self.traces = [trace]
+        else:
+            self.traces = [t for t in trace or () if t]
+        self.attrs: dict = {}
+        self._ann = None
+
+    def __enter__(self) -> "Span":
+        stack = _open_spans()
+        self.parent = stack[-1] if stack else None
+        if self.parent is None:
+            self.path, self.root = self.name, self
+            self.compiled, self._buf = False, []
+        else:
+            self.path = f"{self.parent.path}/{self.name}"
+            self.root = self.parent.root
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            self._ann = jax.profiler.TraceAnnotation(self.name)
+            self._ann.__enter__()
+        stack.append(self)
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = time.monotonic()
+        _open_spans().pop()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self.record_requests()
+        root = self.root
+        root._buf.append((self.path, self.t1 - self.t0, self.items))
+        if root is self:
+            _flush(self)
+        return False
+
+    def record_requests(self, attrs: Optional[dict] = None) -> None:
+        """Record each traced request's ``TRACER`` span now, from the
+        span's start: at the latest when the span closes, earlier when
+        a request's timeline must end before its future settles."""
+        if attrs is not None:
+            self.attrs = attrs
+        traces, self.traces = self.traces, []
+        now = time.monotonic()
+        for trace in traces:
+            TRACER.record(self.name, trace, t0=self.t0, t1=now,
+                          attrs=self.attrs)
+
+
+class _NoSpan:
+    """What :func:`span` returns with the switch off: records nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def record_requests(self, attrs: Optional[dict] = None) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+def span(name: str, trace=None, items: int = 0):
+    """A process span around a step of the program::
+
+        with obs.span("engine.run_sweep", items=n_lanes):
+            ...
+
+    ``trace`` is a request's trace context (or a list of them, one per
+    request the step serves): each also gets a ``TRACER`` span of the
+    same name.  ``items`` counts the step's work (requests, lanes)
+    under ``<path>.items``.  A no-op when the switch is off."""
+    if not state.enabled():
+        return _NO_SPAN
+    return Span(name, trace, items)
+
+
+def _flush(root: Span) -> None:
+    if root.compiled:
+        SPANS.counter(f"{root.path}.compiled").inc()
+        return
+    for path, dur, items in root._buf:
+        SPANS.histogram(path, SPAN_BOUNDS).observe(dur)
+        if items:
+            SPANS.counter(f"{path}.items").inc(items)
+
+
+def _on_compile(event: str, duration: float, **_) -> None:
+    if event not in COMPILE_EVENTS or not state.enabled():
+        return
+    stack = getattr(_local, "stack", None)
+    if stack:
+        stack[0].compiled = True
+        SPANS.histogram("jax.compile_s", SPAN_BOUNDS).observe(duration)
+
+
+def watch_compiles(monitoring) -> None:
+    """Mark the open root span of the thread that compiles (``monitoring``
+    is ``jax.monitoring``; the JAX side calls this once, and repeats are
+    no-ops — ``repro.obs`` itself never imports JAX)."""
+    global _watching
+    with _watch_lock:
+        if _watching:
+            return
+        _watching = True
+    monitoring.register_event_duration_secs_listener(_on_compile)
+

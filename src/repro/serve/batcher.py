@@ -37,6 +37,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Optional, Sequence, Tuple
 
+from .. import obs
 from .queue import SimRequest
 
 __all__ = ["bucket_sizes", "bucket_size", "group_key", "plan_buckets",
@@ -199,14 +200,17 @@ class DynamicBatcher:
         dispatch thread."""
         items = self.queue.drain(max_n=1_000_000, wait_s=wait_s,
                                  linger_s=self.max_wait_ms / 1e3)
-        good = []
-        for req, fut in items:
-            try:
-                group_key(req)
-            except Exception as exc:            # noqa: BLE001
-                if self._quarantined is not None:
-                    self._quarantined.inc()
-                fut.set_exception(exc)
-                continue
-            good.append((req, fut))
-        return plan_buckets(good, self.max_batch)
+        if not items:
+            return []
+        with obs.span("server.plan"):
+            good = []
+            for req, fut in items:
+                try:
+                    group_key(req)
+                except Exception as exc:            # noqa: BLE001
+                    if self._quarantined is not None:
+                        self._quarantined.inc()
+                    fut.set_exception(exc)
+                    continue
+                good.append((req, fut))
+            return plan_buckets(good, self.max_batch)

@@ -10,6 +10,7 @@ requests; ``repro.serve.server.SimServer`` owns the dispatch loop.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from dataclasses import dataclass, field
@@ -206,19 +207,26 @@ class RequestQueue:
     race ``tests/test_served_daemon.py`` pins.
     """
 
-    def __init__(self, registry=None, prefix: str = "queue"):
+    def __init__(self, registry=None, prefix: str = "queue",
+                 spans: bool = False):
         """``registry`` (a ``repro.obs.MetricsRegistry``) opts the queue
         into instrumentation under ``<prefix>.queue.depth`` /
         ``<prefix>.queue.oldest_age_s`` (callback gauges — zero cost
         per enqueue) and ``<prefix>.queue.wait_s`` (queue residency,
         observed at claim time — the admission-queue signal pool
         autoscaling needs).  Traced requests additionally get a
-        retroactive ``<prefix>.queued`` span per claim."""
+        retroactive ``<prefix>.queued`` span per claim.  ``spans``
+        makes ``drain`` time its waits as process spans:
+        ``<prefix>.wait_work`` while blocked on an empty queue,
+        ``<prefix>.coalesce`` for the linger."""
         self._items: list = []
         self._cv = threading.Condition()
         self._closed = False
         self._wait_hist = None
         self._span_name = f"{prefix}.queued"
+        self._spans = spans
+        self._wait_span = f"{prefix}.wait_work"
+        self._linger_span = f"{prefix}.coalesce"
         if registry is not None:
             self._wait_hist = registry.histogram(f"{prefix}.queue.wait_s")
             registry.gauge(f"{prefix}.queue.depth").set_fn(self.__len__)
@@ -251,6 +259,10 @@ class RequestQueue:
             self._items.append((request, future))
             self._cv.notify_all()
 
+    def _span(self, name: str):
+        return (obs_trace.span(name) if self._spans
+                else contextlib.nullcontext())
+
     def drain(self, max_n: int, wait_s: float = 0.1,
               linger_s: float = 0.0) -> list:
         """Return up to ``max_n`` queued ``(request, future)`` pairs.
@@ -260,14 +272,17 @@ class RequestQueue:
         """
         deadline = time.monotonic() + wait_s
         with self._cv:
-            while not self._items and not self._closed:
-                remaining = deadline - time.monotonic()
-                if remaining <= 0 or not self._cv.wait(remaining):
-                    break
+            if not self._items and not self._closed and wait_s > 0:
+                with self._span(self._wait_span):
+                    while not self._items and not self._closed:
+                        remaining = deadline - time.monotonic()
+                        if remaining <= 0 or not self._cv.wait(remaining):
+                            break
             if not self._items:
                 return []
         if linger_s > 0:
-            time.sleep(linger_s)
+            with self._span(self._linger_span):
+                time.sleep(linger_s)
         with self._cv:
             taken, self._items = (self._items[:max_n],
                                   self._items[max_n:])

@@ -27,7 +27,6 @@ determinism contract.
 from __future__ import annotations
 
 import threading
-import time
 from dataclasses import dataclass, replace
 from typing import Callable, Optional
 
@@ -148,8 +147,8 @@ class SimServer:
         self.metrics = obs.MetricsRegistry()
         self._c = obs.catalog.register_counters(
             self.metrics, "server", obs.catalog.SERVER_COUNTERS)
-        self._dispatch_hist = self.metrics.histogram("server.dispatch_s")
-        self._queue = RequestQueue(registry=self.metrics, prefix="server")
+        self._queue = RequestQueue(registry=self.metrics, prefix="server",
+                                   spans=True)
         self._batcher = DynamicBatcher(self._queue, max_batch=max_batch,
                                        max_wait_ms=max_wait_ms,
                                        registry=self.metrics)
@@ -331,17 +330,24 @@ class SimServer:
         return stream, cfg, budgets, scenarios
 
     def _dispatch(self, bucket) -> None:
+        """Run one bucket under its ``serve.dispatch`` span (the engine's
+        spans nest inside it)."""
+        traces = [r.trace for r, _ in bucket.requests if r.trace]
+        with obs.span("serve.dispatch", trace=traces, items=bucket.n) as sp:
+            self._run_bucket(bucket, sp)
+
+    def _run_bucket(self, bucket, sp) -> None:
         from repro.federated import run_simulation_scan, run_batch
         from repro.federated.engine import batch_buckets, batch_dispatch_plan
         from repro.federated.simulation import eval_window
         seq = self._c["dispatch_seq"].inc() - 1      # atomic allocation
-        t_dispatch0 = time.monotonic()
         meta = {"mode": "exact" if bucket.exact else "batched",
                 "bucket": bucket.size, "n_requests": bucket.n,
                 "n_padding": bucket.n_padding, "sharded": False,
                 "seq": seq}
         try:
-            stream, cfg, budgets, scens = self._resolve(bucket)
+            with obs.span("server.resolve"):
+                stream, cfg, budgets, scens = self._resolve(bucket)
             req0 = bucket.requests[0][0]
             scheduled = bucket.scheduled  # group key: the schedule CLASS
             meta["scheduled"] = scheduled
@@ -396,10 +402,11 @@ class SimServer:
                               scens if scheduled else None)[:bucket.n]
         except Exception as exc:                        # noqa: BLE001
             self._c["failed"].inc(bucket.n)
-            self._trace_dispatch(bucket, meta, t_dispatch0, "error")
-            for _, fut in bucket.requests:
-                if not fut.done():
-                    fut.set_exception(exc, execution=dict(meta))
+            self._trace_dispatch(sp, bucket, meta, "error")
+            with obs.span("server.settle"):
+                for _, fut in bucket.requests:
+                    if not fut.done():
+                        fut.set_exception(exc, execution=dict(meta))
             return
         # register_stream may have replaced the stream between _resolve
         # and get_or_build, in which case get_or_build re-inserted a key
@@ -422,31 +429,24 @@ class SimServer:
             self._c["padded_lanes"].inc(bucket.n_padding)
             if meta["sharded"]:
                 self._c["sharded_batches"].inc()
-        self._trace_dispatch(bucket, meta, t_dispatch0, "ok")
-        for (_, fut), res in zip(bucket.requests, results):
-            fut.set_result(res, execution=dict(meta))
+        self._trace_dispatch(sp, bucket, meta, "ok")
+        with obs.span("server.settle"):
+            for (_, fut), res in zip(bucket.requests, results):
+                fut.set_result(res, execution=dict(meta))
 
-    def _trace_dispatch(self, bucket, meta: dict, t0: float,
-                        outcome: str) -> None:
-        """Observe the dispatch duration and, for traced requests, record
-        one ``serve.dispatch`` span each — attrs carry the bucket
-        metadata plus the co-tenant seeds ("batched-with-whom").
-        Observe-only: reads request metadata, never results."""
+    @staticmethod
+    def _trace_dispatch(sp, bucket, meta: dict, outcome: str) -> None:
+        """End each traced request's ``serve.dispatch`` span before its
+        future settles — attrs carry the bucket metadata plus the
+        co-tenant seeds ("batched-with-whom").  Observe-only: reads
+        request metadata, never results."""
         if not obs.enabled():
             return
-        t1 = time.monotonic()
-        self._dispatch_hist.observe(t1 - t0)
-        traced = [r for r, _ in bucket.requests if r.trace]
-        if not traced:
-            return
-        co_seeds = [r.seed for r, _ in bucket.requests[:32]]
         attrs = {k: meta[k] for k in ("mode", "bucket", "n_requests",
                                       "n_padding", "sharded", "seq")}
         attrs["outcome"] = outcome
-        attrs["co_seeds"] = co_seeds
-        for req in traced:
-            obs.TRACER.record("serve.dispatch", req.trace, t0=t0, t1=t1,
-                              attrs=attrs)
+        attrs["co_seeds"] = [r.seed for r, _ in bucket.requests[:32]]
+        sp.record_requests(attrs)
 
     # -- observability ----------------------------------------------------
 

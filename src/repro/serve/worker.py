@@ -105,9 +105,12 @@ class WorkerHandlers:
         s["worker_id"] = self.worker_id
         # accepted but not yet settled — the pool router's load signal
         s["depth"] = s["submitted"] - s["served"] - s["failed"]
-        # the typed instrument tree rides the same RPC: the daemon's
-        # metrics_doc merges these per-worker snapshots fleet-wide
-        s["metrics"] = self.server.metrics.snapshot()
+        # the typed instrument tree rides the same RPC, the process
+        # spans (serve.dispatch and the engine's) beside the server's
+        # instruments: the daemon's metrics_doc merges these per-worker
+        # snapshots fleet-wide
+        s["metrics"] = obs.MetricsRegistry.merge(
+            [self.server.metrics.snapshot(), obs.SPANS.snapshot()])
         return s
 
     def trace(self, params, ctx):
