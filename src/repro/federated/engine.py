@@ -201,9 +201,22 @@ def _make_scan(algo: str, T: int, cfg: SimConfig, data_axis=None,
     eta, xi = jnp.float32(eta), jnp.float32(xi)
 
     def build_body(preds, y, costs, budget):
-        return make_round_body(
+        body, init_carry = make_round_body(
             algo, preds, y, costs, cfg, jnp.asarray(budget, jnp.float32),
             eta, xi, data_axis=data_axis)
+
+        # The transmit set is stacked as packed uint32 words, not as a
+        # (K,) bool row: under the sweeps' nested vmaps the TPU compiler
+        # lays a pred stack out with the round axis minor-most, so each
+        # round would write one strided column across every tile.  A word
+        # stack is laid out like the float stacks, round axis major.  The
+        # host unpacks the words (``_host_outs``) before any result reads
+        # them.
+        def packed_body(carry, x):
+            carry, out = body(carry, x)
+            return carry, dict(out, sel=_pack_sel(out["sel"]))
+
+        return packed_body, init_carry
 
     if scheduled:
         def scan(preds, y, costs, key, budget, sched):
@@ -219,6 +232,36 @@ def _make_scan(algo: str, T: int, cfg: SimConfig, data_axis=None,
             return outs
 
     return scan
+
+
+def _pack_sel(sel):
+    """A round's ``(K,)`` bool transmit set as uint32 words: bit ``k % 32``
+    of word ``k // 32`` holds ``sel[k]``.  One scalar word for ``K <= 32``,
+    else a trailing axis of ``ceil(K / 32)`` words."""
+    K = sel.shape[-1]
+    n_words = -(-K // 32)
+    bits = jnp.pad(sel.astype(jnp.uint32), (0, 32 * n_words - K))
+    words = jnp.sum(bits.reshape(n_words, 32)
+                    << jnp.arange(32, dtype=jnp.uint32),
+                    axis=-1, dtype=jnp.uint32)
+    return words[0] if n_words == 1 else words
+
+
+def _unpack_sel(words, K: int) -> np.ndarray:
+    """Host inverse of ``_pack_sel`` over stacked rounds: words
+    ``(..., T)`` (``K <= 32``) or ``(..., T, ceil(K / 32))`` to the exact
+    bool masks ``(..., T, K)``."""
+    words = np.ascontiguousarray(words, dtype="<u4")
+    if K <= 32:
+        words = words[..., None]
+    return np.unpackbits(words.view(np.uint8), axis=-1, count=K,
+                         bitorder="little").view(bool)
+
+
+def _host_outs(outs, K: int) -> dict:
+    """The fetched per-round outputs with ``sel`` unpacked to bool masks:
+    what every result built from them reads."""
+    return dict(outs, sel=_unpack_sel(outs["sel"], K))
 
 
 def _get_scan(algo: str, T: int, cfg: SimConfig, sweep: str = "",
@@ -351,7 +394,7 @@ def run_simulation_scan(algo: str, preds, y, costs, T: int,
         outs = scan(preds, y, costs, jax.random.PRNGKey(cfg.seed),
                     jnp.float32(cfg.budget), comp.arrays)
         thresh = cfg.budget * comp.scale
-    outs = jax.tree.map(np.asarray, outs)
+    outs = _host_outs(jax.tree.map(np.asarray, outs), preds.shape[0])
     return _to_result(outs, T, thresh, algo)
 
 
@@ -483,6 +526,7 @@ def run_batch(algo: str, preds, y, costs, T: int, cfg: SimConfig,
                     for k, v in o.items():
                         outs[k][idx] = v
         with obs.span("engine.unpack"):
+            outs = _host_outs(outs, preds.shape[0])
             if scale is None:
                 thresh = [budgets[i] for i in range(n)]
             elif scale.ndim == 1:       # one shared scenario: (T,) factors
@@ -787,7 +831,8 @@ def run_sweep_sharded(algo: str, preds, y, costs, T: int, cfg: SimConfig,
                 args += (sweep_sharding.pad_lane_tree(sched, n_sweep),)
         outs = _run_program(fn, *args)
         with obs.span("engine.unpack"):
-            outs = jax.tree.map(lambda a: a[:n_cfg], outs)
+            outs = _host_outs(jax.tree.map(lambda a: a[:n_cfg], outs),
+                              preds.shape[0])
             if grid_shape is not None:
                 outs = jax.tree.map(
                     lambda a: a.reshape(grid_shape + a.shape[1:]), outs)
@@ -867,5 +912,5 @@ def run_sweep(algo: str, preds, y, costs, T: int, cfg: SimConfig,
                 args += (sched,)
         outs = _run_program(fn, *args)
         with obs.span("engine.unpack"):
-            return SweepResult(outs, seeds, budgets_arr, T,
-                               budget_scale=scale)
+            return SweepResult(_host_outs(outs, preds.shape[0]), seeds,
+                               budgets_arr, T, budget_scale=scale)

@@ -66,7 +66,8 @@ _ENGINE_CALL = {
     "engine.launch": "the jitted call's enqueue (where compiles land)",
     "engine.device_wait": "blocked until the call's outputs are ready",
     "engine.fetch": "device-to-host copy of the per-round outputs",
-    "engine.unpack": "building the SweepResult / per-lane SimResults",
+    "engine.unpack": "transmit-set words to bool masks, then the "
+                     "SweepResult / per-lane SimResults",
 }
 SPANS = {
     "engine.run_sweep": ("span", "one run_sweep / run_sweep_sharded call"),

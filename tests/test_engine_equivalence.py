@@ -14,13 +14,17 @@ Two layers of defense:
   (the pre-engine ``_client_losses`` / ``_clients_for_round`` logic,
   resurrected here as test oracles)."""
 
+from dataclasses import replace
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
-from repro.federated import (SimConfig, run_simulation_reference,
-                             run_simulation_scan, run_sweep)
+from repro.federated import (SimConfig, engine, run_batch,
+                             run_simulation_reference, run_simulation_scan,
+                             run_sweep)
 from repro.federated.simulation import (client_window_losses,
                                         fedboost_window_grad,
                                         n_clients_traceable)
@@ -193,3 +197,88 @@ def test_sweep_budget_grid():
     # larger budgets admit (weakly) larger transmit sets on average
     mean_sel = sw.sel_sizes.mean(axis=(1, 2))
     assert mean_sel[0] <= mean_sel[-1] + 1e-9
+
+
+# ---------------------------------------------------------------------------
+# Transmit sets cross the device boundary as packed uint32 words
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("K", [1, 22, 32, 40])
+def test_sel_pack_round_trip(K):
+    rng = np.random.default_rng(K)
+    masks = rng.random((3, 5, K)) < 0.5          # (lanes, rounds, K)
+    masks[0, 0], masks[0, 1] = True, False
+    words = np.asarray(jax.vmap(jax.vmap(engine._pack_sel))(
+        jnp.asarray(masks)))
+    n_words = -(-K // 32)
+    assert words.dtype == np.uint32
+    assert words.shape == (3, 5) + (() if n_words == 1 else (n_words,))
+    back = engine._unpack_sel(words, K)
+    assert back.dtype == np.bool_
+    np.testing.assert_array_equal(back, masks)
+    # bit k % 32 of word k // 32 holds sel[k]: K = 32 sets bit 31, K = 40
+    # sets bit 7 of the second word
+    last = np.zeros(K, bool)
+    last[-1] = True
+    want = np.zeros(n_words, np.uint32)
+    want[(K - 1) // 32] = np.uint32(1) << np.uint32((K - 1) % 32)
+    np.testing.assert_array_equal(
+        np.asarray(engine._pack_sel(jnp.asarray(last))).reshape(-1), want)
+
+
+@pytest.mark.parametrize("K", [8, 40])
+def test_sweep_result_gets_bool_masks(monkeypatch, K):
+    """The outputs ``run_sweep`` hands to ``SweepResult`` hold the bool
+    transmit sets (budgets, seeds, T, K), each lane's equal to its batched
+    lane and its solo run (FedBoost's draws do not depend on its
+    weights, so batched and solo selections agree)."""
+    seen = {}
+
+    class Keep(engine.SweepResult):
+        def __init__(self, outs, *args, **kw):
+            super().__init__(outs, *args, **kw)
+            seen["sel"] = outs["sel"]
+
+    monkeypatch.setattr(engine, "SweepResult", Keep)
+    preds, y, costs = _stream(K=K)
+    cfg = SimConfig()
+    T, seeds, budgets = 40, [0, 1], [1.0, 2.0]
+    sw = run_sweep("fedboost", preds, y, costs, T=T, cfg=cfg, seeds=seeds,
+                   budgets=budgets)
+    sel = seen["sel"]
+    assert sel.dtype == np.bool_ and sel.shape == (2, 2, T, K)
+    np.testing.assert_array_equal(sel.sum(-1), sw.sel_sizes)
+    lanes = run_batch("fedboost", preds, y, costs, T, cfg,
+                      seeds=seeds * 2, budgets=np.repeat(budgets, 2))
+    for i, b in enumerate(budgets):
+        for j, s in enumerate(seeds):
+            solo = run_simulation_scan("fedboost", preds, y, costs, T,
+                                       replace(cfg, seed=s, budget=b))
+            np.testing.assert_array_equal(sel[i, j],
+                                          lanes[2 * i + j].sel_masks)
+            np.testing.assert_array_equal(sel[i, j], solo.sel_masks)
+
+
+def test_batch_and_solo_masks_are_bool():
+    preds, y, costs = _stream()
+    cfg = SimConfig(budget=2.0)
+    T, budgets = 30, [1.0, 1.0, 2.0, 2.0]
+    assert engine.batch_buckets("eflfg", budgets) is not None
+    solo = run_simulation_scan("eflfg", preds, y, costs, T, cfg)
+    batch = run_batch("eflfg", preds, y, costs, T, cfg, seeds=[0, 1, 2, 3],
+                      budgets=budgets)
+    for r in [solo] + batch:
+        assert r.sel_masks.dtype == np.bool_ and r.sel_masks.shape == (T, 8)
+        np.testing.assert_array_equal(r.sel_masks.sum(1), r.sel_sizes)
+
+
+@pytest.mark.parametrize("algo", ["eflfg", "fedboost"])
+def test_scan_matches_reference_two_word_masks(algo):
+    """K = 40 packs each round's transmit set into two words."""
+    preds, y, costs = _stream(K=40, seed=5)
+    cfg = SimConfig(budget=4.0, seed=1)
+    T = 60
+    ref = run_simulation_reference(algo, preds, y, costs, T=T, cfg=cfg)
+    eng = run_simulation_scan(algo, preds, y, costs, T=T, cfg=cfg)
+    np.testing.assert_array_equal(ref.sel_masks, eng.sel_masks)
+    assert ref.sel_masks.any(axis=0)[32:].any()   # the second word is used

@@ -9,7 +9,9 @@ window W=100:
 * ``client_eval`` in its three variants (with / without the FedBoost
   gradient, and with the scenario schedule operands);
 * ``server_plan`` and ``server_update``;
-* the default (fused) EFL-FG scan, solo and as a 16-lane flat vmap.
+* the default (fused) EFL-FG scan, solo and as a 16-lane flat vmap;
+* the FedBoost budget x seed grid sweep, whose per-round transmit sets
+  must be stacked with the round axis major.
 
 Each asserts that a Mosaic kernel (``tpu_custom_call``) is in the
 compiled program and reads its ``memory_analysis()``.  A compile that
@@ -21,6 +23,7 @@ imports every test file.
 """
 
 import os
+import re
 
 import pytest
 
@@ -136,3 +139,24 @@ def test_fused_eflfg_scan_compiles(one_chip, compiled_kernels, lanes):
             return jax.vmap(lambda k, b: scan(p, y, c, k, b))(keys, budgets)
         _compile_check(flat, *stream, s((lanes,) + key.shape, key.dtype),
                        s((lanes,)))
+
+
+def test_grid_sweep_stacks_transmit_sets_round_major(
+        one_chip, compiled_kernels, monkeypatch):
+    """The benchmark's sweep (FedBoost, 4 budgets x 10 seeds, energy
+    shapes): a pred stack of the rounds' transmit sets would be laid out
+    with the round axis minor-most, and written one strided column per
+    round.  The packed uint32 word stack takes the float stacks' layout."""
+    monkeypatch.setattr(engine, "_SCAN_CACHE", {})
+    fn = engine._get_scan("fedboost", T, SimConfig(), sweep="grid")
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    s = lambda *a: _sds(one_chip, *a)
+    text = fn.lower(s((K, N_STREAM)), s((N_STREAM,)), s((K,)),
+                    s((10,) + key.shape, key.dtype),
+                    s((4,))).compile().as_text()
+    writes = [ln for ln in text.splitlines() if "dynamic-update-slice" in ln]
+    assert not [ln for ln in writes if f"= pred[{T}," in ln]
+    # the sel stack: one word per (round, budget, seed), minor_to_major
+    # ending in dim 0 (rounds)
+    sel = re.findall(rf"= u32\[{T},4,10\]\{{([0-9,]+)", "\n".join(writes))
+    assert sel and all(m.split(",")[-1] == "0" for m in sel)
