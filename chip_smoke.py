@@ -209,11 +209,10 @@ def phase_kernels(jax, preds, y, costs):
     for r in (0, 1, 7, 31, 63):
         st = jax.tree.map(lambda a: a[r], states)
         gumbel = jax.random.gumbel(jax.random.PRNGKey(1000 + r), (K,))
-        plan = sr_ops.server_plan(st.log_w, st.log_u, st.log_w_prev_sums,
+        plan = sr_ops.server_plan(st.log_w, st.log_u, st.prev_adj,
                                   costs, budget, gumbel, xi)
-        plan_ref = sr_ref.server_plan_ref(st.log_w, st.log_u,
-                                          st.log_w_prev_sums, costs, budget,
-                                          gumbel, xi)
+        plan_ref = sr_ref.server_plan_ref(st.log_w, st.log_u, st.prev_adj,
+                                          costs, budget, gumbel, xi)
         for f in ("adj", "dom", "drawn", "sel", "graph_iters"):
             _close(f"server_plan.{f} r{r}", getattr(plan, f),
                    getattr(plan_ref, f), exact=True)
@@ -224,21 +223,20 @@ def phase_kernels(jax, preds, y, costs):
         # positions for the compiled kernel's lane frame
         for cursor in (int(cursors[r]), 0, 127, 128, 5000, n_stream - 3,
                        n_stream - 1):
-            for W, n_t, weighting, w in ((5, 5, "log", st.log_w),
-                                         (5, 5, "none", plan_ref.mix),
-                                         (100, 37, "log", st.log_w)):
+            for W, n_t, with_grad in ((5, 5, False), (5, 5, True),
+                                      (100, 37, False)):
                 pe, ye = ext[W]
-                args = (pe, ye, jnp.int32(cursor), jnp.int32(n_t), w,
-                        plan_ref.sel)
+                args = (pe, ye, jnp.int32(cursor), jnp.int32(n_t),
+                        plan_ref.mix)
                 kw = dict(loss_scale=cfg.loss_scale, window=W,
-                          weighting=weighting, with_grad=weighting == "none")
+                          with_grad=with_grad)
                 sched = dict(
                     active=jnp.asarray(rng.random(W) < 0.7).at[0].set(True),
                     shift=jnp.float32(0.25))
                 for extra in ({}, sched):
                     out = ce_ops.client_eval(*args, **kw, **extra)
                     want = ce_ref.client_eval_ref(*args, cfg.loss_scale, W,
-                                                  weighting, **extra)
+                                                  **extra)
                     for f in out._fields:
                         if getattr(out, f) is None:
                             continue
@@ -247,8 +245,7 @@ def phase_kernels(jax, preds, y, costs):
                             getattr(out, f), getattr(want, f)))
                     n_checked += 1
         ev = ce_ref.client_eval_ref(*ext[5], cursors[r], jnp.int32(5),
-                                    st.log_w, plan_ref.sel, cfg.loss_scale,
-                                    5, "log")
+                                    plan_ref.mix, cfg.loss_scale, 5)
         upd_args = (plan_ref.adj, plan_ref.p, plan_ref.sel, plan_ref.drawn,
                     ev.model_losses, ev.ens_norm, st.log_w, st.log_u, eta)
         upd = sr_ops.server_update(*upd_args)

@@ -8,7 +8,7 @@ Public API:
   RegretTracker, theorem1_bound                                   eq. 10/11
 """
 
-from .graph import feedback_graph, feedback_graph_np, row_log_weight_sums
+from .graph import feedback_graph, feedback_graph_np
 from .domset import dominating_set, dominating_set_np, independence_number_np
 from . import policy
 from .eflfg import (EFLFGState, EFLFGRoundOut, init_state, plan_round,
@@ -20,7 +20,7 @@ from .regret import (RegretCarry, regret_init, regret_update, regret_value,
                      RegretTracker, theorem1_bound)
 
 __all__ = [
-    "feedback_graph", "feedback_graph_np", "row_log_weight_sums",
+    "feedback_graph", "feedback_graph_np",
     "dominating_set", "dominating_set_np", "independence_number_np",
     "policy",
     "EFLFGState", "EFLFGRoundOut", "init_state", "plan_round",

@@ -2,8 +2,8 @@
 
 The server state carries log-weights for both the per-model confidences
 ``w`` (eq. 9a) and the per-node ensemble confidences ``u`` (eq. 9b), plus
-the previous round's out-neighborhood weight sums that feed the weight
-constraint in eq. (2).
+the previous round's feedback graph, whose out-neighborhoods bound the
+next round's weights in eq. (2).
 
 The round step is model-agnostic: it consumes the (K, n_clients) matrix of
 per-model *per-client* losses and the (n_clients,) ensemble losses — who
@@ -18,7 +18,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from .graph import feedback_graph, row_log_weight_sums
+from .graph import feedback_graph
 from .domset import dominating_set
 from .numerics import ladder_sum
 from . import policy
@@ -26,13 +26,12 @@ from . import policy
 __all__ = ["EFLFGState", "EFLFGRoundOut", "init_state", "plan_round",
            "update_state", "round_step", "make_eflfg_scan_body"]
 
-_LOG_INF = 1e30
-
 
 class EFLFGState(NamedTuple):
     log_w: jnp.ndarray          # (K,) model confidence, log space
     log_u: jnp.ndarray          # (K,) node confidence, log space
-    log_w_prev_sums: jnp.ndarray  # (K,) log W_{k,t-1} of prev out-neighborhoods
+    prev_adj: jnp.ndarray       # (K, K) bool previous round's feedback graph
+                                # (all True before the first round)
     t: jnp.ndarray              # round counter
 
 
@@ -44,20 +43,18 @@ class EFLFGRoundOut(NamedTuple):
     sel: jnp.ndarray            # (K,) bool, S_t = N_out(I_t)
     mix: jnp.ndarray            # (K,) eq. (5) ensemble mixture weights
     round_cost: jnp.ndarray     # scalar, sum of costs of S_t
-    log_w: jnp.ndarray          # (K,) log-weights the mixture derives from
-                                # (lets fused client eval redo eq. (5)
-                                # in-kernel; see repro.kernels.client_eval)
     graph_iters: jnp.ndarray    # scalar int32: the graph builder's OWN
                                 # productive append-step count this round
                                 # (feeds SweepResult.lockstep_waste)
 
 
 def init_state(K: int) -> EFLFGState:
-    """w_{k,1} = u_{k,1} = 1; no previous neighborhood (constraint off)."""
+    """w_{k,1} = u_{k,1} = 1; no previous neighborhood: every node stands
+    in for it, so eq. (2)'s weight bound never binds in round 1."""
     return EFLFGState(
         log_w=jnp.zeros((K,)),
         log_u=jnp.zeros((K,)),
-        log_w_prev_sums=jnp.full((K,), _LOG_INF),
+        prev_adj=jnp.ones((K, K), bool),
         t=jnp.zeros((), jnp.int32),
     )
 
@@ -69,15 +66,14 @@ def plan_round(state: EFLFGState, key: jax.Array, costs: jnp.ndarray,
     This is the part that must run *before* any model is sent to clients.
     """
     adj, iters = feedback_graph(state.log_w, costs, budget,
-                                state.log_w_prev_sums, with_iters=True)
+                                state.prev_adj, with_iters=True)
     dom = dominating_set(adj)
     p = policy.pmf(state.log_u, dom, xi)
     drawn = policy.draw_node(key, p)
     sel = adj[drawn]
     mix = policy.ensemble_mix_weights(state.log_w, sel)
     round_cost = ladder_sum(jnp.where(sel, costs, 0.0))
-    return EFLFGRoundOut(adj, dom, p, drawn, sel, mix, round_cost,
-                         state.log_w, iters)
+    return EFLFGRoundOut(adj, dom, p, drawn, sel, mix, round_cost, iters)
 
 
 def update_state(state: EFLFGState, plan: EFLFGRoundOut,
@@ -89,11 +85,9 @@ def update_state(state: EFLFGState, plan: EFLFGRoundOut,
         model_losses, ens_loss, plan.sel, plan.drawn, plan.p, q)
     log_w = policy.exp_weight_update(state.log_w, eta, ell)
     log_u = policy.exp_weight_update(state.log_u, eta, ell_hat)
-    # W_{k,t} sums for the eq. (2) constraint of the *next* round, evaluated
-    # with the *updated* weights (the constraint compares against the sum of
-    # current-round neighborhoods under the weights the next round sees).
-    log_prev = row_log_weight_sums(plan.adj, log_w)
-    return EFLFGState(log_w, log_u, log_prev, state.t + 1)
+    # the next round's eq. (2) bounds each row by this round's
+    # neighborhood under the weights that round sees (the updated ones)
+    return EFLFGState(log_w, log_u, plan.adj, state.t + 1)
 
 
 def make_eflfg_scan_body(loss_fn, costs: jnp.ndarray, budget: jnp.ndarray,

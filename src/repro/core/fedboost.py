@@ -66,8 +66,8 @@ def fedboost_plan(state: FedBoostState, key: jax.Array, costs: jnp.ndarray,
     # guarantee at least one transmitted model (highest current weight)
     best = jnp.argmax(state.alpha)
     sel = sel | ((jnp.arange(K) == best) & ~jnp.any(sel))
-    # ladder reductions (core.numerics) keep the mixing bit-identical to
-    # the fused client kernel's mix_weights_ref("linear")
+    # ladder reductions (core.numerics) keep the mixing bit-identical in
+    # every program variant
     masked = jnp.where(sel, state.alpha, 0.0)
     mix = masked / jnp.maximum(ladder_sum(masked), 1e-12)
     cost = ladder_sum(jnp.where(sel, costs, 0.0))

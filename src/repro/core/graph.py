@@ -9,8 +9,22 @@ repeatedly append the vertex maximizing
 subject to (eq. 2):
   * cumulative cost stays within the round budget ``B_t``,
   * cumulative *weight* of the out-neighborhood does not exceed the
-    out-neighborhood weight of the previous round (``W_prev``),
+    weight of the row's previous-round out-neighborhood ``P_k`` under
+    this round's weights (``W_prev``), with a 1e-6 relative slack,
   * no duplicates.
+
+The weight bound compares the weights of two sets, so it is evaluated on
+their difference: a candidate ``i`` for row ``k`` (grown so far to ``A``)
+is admitted iff
+
+    sum_{A - P_k} w + w_i  <=  sum_{P_k - A} w + 1e-6 * sum_{P_k} w,
+
+which is ``sum_A w + w_i <= (1 + 1e-6) W_prev`` with the weight of
+``A & P_k`` taken off both sides.  A row that stays inside its previous
+neighborhood is then admitted exactly on any platform: the left side is
+``w_i`` alone and the right side a sum of non-negative terms that holds
+it.  Before the first round the previous neighborhood is every node, so
+the bound never binds.
 
 The greedy loop is data dependent, so the JAX implementation is a bounded
 ``lax.while_loop`` (at most K-1 appends) that advances ALL K source
@@ -27,7 +41,8 @@ whose body advances all lanes with (B, K, K) ops, unrolled
 done masks so converged lanes execute masked no-ops and their
 ``n_iters`` stop counting.  The rule is bit-equal to per-lane solo calls
 by construction: a lane's inactivity predicate is monotone (members,
-cost sums and weight sums only grow), so extra trips after a lane
+cost sums and the weight outside the previous neighborhood only grow,
+the weight left inside it only shrinks), so extra trips after a lane
 converges change nothing, and every reduction runs over K axes only.
 
 A pure-NumPy reference (`feedback_graph_np`) mirrors the paper's
@@ -35,8 +50,8 @@ pseudo-code literally and is used as the oracle in property tests.
 
 Weights are carried in log space throughout the library: after many
 exponential-weight updates the raw weights underflow float32, while
-log-weights stay exact.  All comparisons in eq. (2)/(3) are performed with
-``logsumexp`` so the semantics are identical to the paper's.
+log-weights stay exact.  The builder exponentiates them once per round,
+shifted per source row (``_graph_tables``).
 """
 
 from __future__ import annotations
@@ -48,15 +63,15 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .numerics import ladder_logsumexp
+from .numerics import exp, fma_fence, ladder_sum
 
 __all__ = [
     "feedback_graph",
     "feedback_graph_np",
-    "row_log_weight_sums",
 ]
 
-_NEG_INF = -1e30
+# eq. (2)'s relative slack on the previous neighborhood's weight
+_SLACK = 1e-6
 
 # Inner unroll of the batched while body: 2 greedy appends per loop trip
 # halves the while-loop machinery overhead (measured ~1.2x on the batched
@@ -66,57 +81,82 @@ _NEG_INF = -1e30
 _BATCH_UNROLL = 2
 
 
-def _graph_tables(log_w, costs, budget, log_w_prev_sums):
-    """Per-round precomputation shared by the solo and batched loops.
+def _weight_bad(E, prev, mask, over, slack):
+    """Eq. (2)'s weight bound per (row, candidate): True where admitting
+    the candidate would lift the row past its previous neighborhood's
+    weight.  ``under`` is recomputed from the members, never decremented,
+    so it stays monotone and exact for rows inside ``prev``."""
+    under = ladder_sum(jnp.where(prev & ~mask, E, 0.0), axis=-1,
+                       fence=False)
+    return over[..., None] + E > (under + slack)[..., None]
+
+
+def _graph_tables(log_w, costs, budget, prev):
+    """Per-round precomputation shared by the solo and batched loops and
+    the fused server kernel.
 
     The while body runs on the scan engine's hot path, where every (K, K)
-    op costs ~1us of dispatch on CPU, so the log-space comparisons are
-    rewritten in exp space once:
+    op costs ~1us of dispatch on CPU, so the log-weights are
+    exponentiated once per round:
+      eq. (2) weight bound:  ``E[k, j] = exp(log_w_j - m_k)``, shifted
+        per row by the row's largest previous-neighborhood log-weight
+        ``m_k`` (so ``sum_{P_k} E`` lies in [1, K]; a node far above it
+        overflows to inf, which only ever refuses it).  A row ``A``
+        carries ``over = sum_{A - P_k} E`` (grown by exact one-hot
+        adds) and recomputes ``under = sum_{P_k - A} E`` as a ladder
+        sum each trip; candidate ``i`` fails iff
+        ``over + E[k, i] > under + slack_k`` with
+        ``slack_k = 1e-6 * sum_{P_k} E``.  For ``A`` inside ``P_k``,
+        ``over`` is exactly 0 and ``under`` a sum of non-negative terms
+        that holds ``E[k, i]`` — rounding is monotone, so such a
+        candidate is never refused.
       eq. (3) argmax:  log_w_j - log(den) -> W_ROW[i, j] / den, with
         ``W_ROW[i, j] = exp(log_w_j - shift_i)`` shifted per row by the
         row's best *initially eligible* log-weight — so each row's
         leading candidate scores ~1/den whatever the global weight
         spread.  Within a row the shift is a constant positive factor:
         the argmax is unchanged.
-      eq. (2) weight constraint:  logaddexp(W_i, log_w_j) <= lps_i + tol
-        ->  s_i + E_ij <= 1  with  s_i = exp(W_i - lps_i - tol) and
-        E_ij = exp(log_w_j - lps_i - tol); appending d_i advances the
-        row sum incrementally as  s_i += E[i, d_i]  (exact: exp turns
-        the log-sum into a plain sum).  lps = 1e30 (round 1) makes both
-        terms 0, disabling the constraint exactly as before.
 
-    Returns ``(E, s0, W_ROW)`` with shapes (K, K), (K,), (K, K).
+    Returns ``(E, over0, slack, W_ROW)`` with shapes (K, K), (K,), (K,),
+    (K, K).
     """
     K = log_w.shape[0]
-    thresh = log_w_prev_sums + 1e-6                        # fp tolerance
-    E = jnp.exp(log_w[None, :] - thresh[:, None])
-    s0 = jnp.exp(log_w - thresh)
+    eye = jnp.eye(K, dtype=bool)
+    m = jnp.max(jnp.where(prev, log_w[None, :], -jnp.inf), axis=1)
+    m = jnp.where(jnp.isfinite(m), m, 0.0)             # empty-row guard
+    E = exp(log_w[None, :] - m[:, None])
+    # fenced: the product must round before ``under + slack`` in every
+    # program variant (numerics.fma_fence)
+    slack = fma_fence(_SLACK * ladder_sum(jnp.where(prev, E, 0.0), axis=1,
+                                          fence=False))
+    # one term: the self loop's weight when it lies outside P_k
+    over0 = jnp.sum(jnp.where(eye & ~prev, E, 0.0), axis=1)
     # Row shift = the row's best log-weight among its round-start
     # eligible set (self/over-budget/over-weight excluded).  Rows with no
     # eligible candidate fall back to shift 0 (log_w <= 0 throughout the
     # library, so exp stays bounded); they append nothing either way.
     den0 = costs[:, None] + costs[None, :]
-    bad0 = (jnp.eye(K, dtype=bool) | (den0 > budget)
-            | (E > (1.0 - s0)[:, None]))
-    m = jnp.max(jnp.where(bad0, -jnp.inf, log_w[None, :]), axis=1)
-    shift = jnp.where(jnp.isfinite(m), m, 0.0)
-    W_ROW = jnp.exp(log_w[None, :] - shift[:, None])
-    return E, s0, W_ROW
+    bad0 = eye | (den0 > budget) | _weight_bad(E, prev, eye, over0, slack)
+    best = jnp.max(jnp.where(bad0, -jnp.inf, log_w[None, :]), axis=1)
+    shift = jnp.where(jnp.isfinite(best), best, 0.0)
+    W_ROW = exp(log_w[None, :] - shift[:, None])
+    return E, over0, slack, W_ROW
 
 
 @jax.custom_batching.custom_vmap
-def _fg(log_w, costs, budget, log_w_prev_sums):
+def _fg(log_w, costs, budget, prev):
     """Solo Algorithm 1: ``(K,) args -> (adjacency (K, K), n_iters)``."""
     K = log_w.shape[0]
     rows = jnp.arange(K)
-    E, s0, W_ROW = _graph_tables(log_w, costs, budget, log_w_prev_sums)
+    E, over0, slack, W_ROW = _graph_tables(log_w, costs, budget, prev)
 
     def body(carry):
-        mask, cost_sum, s, _, iters = carry
+        mask, cost_sum, over, _, iters = carry
         den = cost_sum[:, None] + costs[None, :]
         # ineligibility folded into one sentinel chain: eligible ratios are
         # >= 0 (W_ROW, den > 0), so -1 marks members/over-budget/over-weight
-        bad = mask | (den > budget) | (E > (1.0 - s)[:, None])
+        bad = (mask | (den > budget)
+               | _weight_bad(E, prev, mask, over, slack))
         ratio = jnp.where(bad, -1.0, W_ROW / den)
         best, idx = jax.lax.top_k(ratio, 1)                # one fused kernel
         d = idx[:, 0]                                      # (K,) appends
@@ -126,27 +166,26 @@ def _fg(log_w, costs, budget, log_w_prev_sums):
         upd = (rows[None, :] == d[:, None]) & active[:, None]
         mask = mask | upd
         cost_sum = cost_sum + jnp.where(active, costs[d], 0.0)
-        s = s + jnp.sum(jnp.where(upd, E, 0.0), axis=1)
+        over = over + jnp.sum(jnp.where(upd & ~prev, E, 0.0), axis=1)
         any_active = jnp.any(active)
-        return (mask, cost_sum, s, any_active,
+        return (mask, cost_sum, over, any_active,
                 iters + any_active.astype(jnp.int32))
 
     carry0 = (jnp.eye(K, dtype=bool),                      # self loops
-              costs, s0, jnp.bool_(True), jnp.int32(0))
+              costs, over0, jnp.bool_(True), jnp.int32(0))
     mask, _, _, _, iters = jax.lax.while_loop(lambda c: c[3], body, carry0)
     return mask, iters
 
 
 @_fg.def_vmap
-def _fg_batched(axis_size, in_batched, log_w, costs, budget,
-                log_w_prev_sums):
+def _fg_batched(axis_size, in_batched, log_w, costs, budget, prev):
     """Batched-native Algorithm 1: one flat while_loop over the batch.
 
     Replaces JAX's generic while-loop batching (which would re-trace the
     solo body under vmap) with a hand-batched loop: per-lane done masks,
     ``_BATCH_UNROLL`` appends per trip, per-lane ``n_iters`` counters
     that freeze on convergence.  Bit-equal to per-lane solo calls —
-    pinned by ``tests/test_feedback_graph.py``.
+    pinned by ``tests/test_feedback_graph_batched.py``.
     """
     B = axis_size
 
@@ -154,18 +193,19 @@ def _fg_batched(axis_size, in_batched, log_w, costs, budget,
         x = jnp.asarray(x)
         return x if batched else jnp.broadcast_to(x, (B,) + x.shape)
 
-    log_w, costs, budget, lps = (
+    log_w, costs, budget, prev = (
         bcast(a, b) for a, b in zip(
-            (log_w, costs, budget, log_w_prev_sums), in_batched))
+            (log_w, costs, budget, prev), in_batched))
     K = log_w.shape[-1]
     rows = jnp.arange(K)
-    E, s0, W_ROW = jax.vmap(_graph_tables)(log_w, costs, budget, lps)
+    E, over0, slack, W_ROW = jax.vmap(_graph_tables)(log_w, costs, budget,
+                                                     prev)
 
     def one(c):
-        mask, cs, s, it = c
+        mask, cs, over, it = c
         den = cs[..., None] + costs[:, None, :]
         bad = (mask | (den > budget[:, None, None])
-               | (E > (1.0 - s)[..., None]))
+               | _weight_bad(E, prev, mask, over, slack))
         ratio = jnp.where(bad, -1.0, W_ROW / den)
         best, idx = jax.lax.top_k(ratio, 1)
         d = idx[..., 0]                                    # (B, K)
@@ -174,9 +214,9 @@ def _fg_batched(axis_size, in_batched, log_w, costs, budget,
         mask = mask | upd
         cs = cs + jnp.where(active,
                             jnp.take_along_axis(costs, d, axis=-1), 0.0)
-        s = s + jnp.sum(jnp.where(upd, E, 0.0), axis=-1)
+        over = over + jnp.sum(jnp.where(upd & ~prev, E, 0.0), axis=-1)
         it = it + jnp.any(active, axis=-1).astype(jnp.int32)
-        return (mask, cs, s, it), active
+        return (mask, cs, over, it), active
 
     def body(cc):
         c, _ = cc
@@ -185,7 +225,7 @@ def _fg_batched(axis_size, in_batched, log_w, costs, budget,
         return c, jnp.any(active)
 
     carry0 = (jnp.tile(jnp.eye(K, dtype=bool)[None], (B, 1, 1)),
-              costs, s0, jnp.zeros((B,), jnp.int32))
+              costs, over0, jnp.zeros((B,), jnp.int32))
     (mask, _, _, iters), _ = jax.lax.while_loop(
         lambda cc: cc[1], body, (carry0, jnp.bool_(True)))
     return (mask, iters), (True, True)
@@ -193,8 +233,7 @@ def _fg_batched(axis_size, in_batched, log_w, costs, budget,
 
 @functools.partial(jax.jit, static_argnames=("with_iters",))
 def feedback_graph(log_w: jnp.ndarray, costs: jnp.ndarray, budget: jnp.ndarray,
-                   log_w_prev_sums: jnp.ndarray, *,
-                   with_iters: bool = False):
+                   prev: jnp.ndarray, *, with_iters: bool = False):
     """Algorithm 1.  Returns the boolean adjacency ``A`` with
     ``A[k, i] = True`` iff ``v_i`` is an out-neighbor of ``v_k`` — or
     ``(A, n_iters)`` with ``with_iters``, where ``n_iters`` is the number
@@ -226,33 +265,28 @@ def feedback_graph(log_w: jnp.ndarray, costs: jnp.ndarray, budget: jnp.ndarray,
     the spread stays far below that (~45 nats at T=2000) and such models
     carry negligible eq.-(5) mixture weight anyway; for extreme horizons,
     re-derive eta or shard the run before the spread approaches float32
-    exp range.
+    exp range.  The eq.-(2) weights are shifted per row by the previous
+    neighborhood's largest log-weight: a weight ~88 nats below it
+    underflows to 0, far inside the bound's 1e-6 slack.
 
     Args:
       log_w: (K,) log confidence weights ``log w_{k,t}``.
       costs: (K,) transmission costs ``c_k`` (positive).
       budget: scalar round budget ``B_t``.
-      log_w_prev_sums: (K,) ``log sum_{j in N_out_{k,t-1}} w_{j,t-1}``;
-        pass ``+inf``-like values (e.g. 1e30) on the first round, which
-        disables the weight constraint exactly as the paper's t=1 round
-        (where no previous neighborhood exists).
+      prev: (K, K) bool, the previous round's adjacency ``N_out_{k,t-1}``
+        (self loops included); eq. (2) bounds each row by its weight
+        under ``log_w``.  Pass all-True on the first round, where no
+        previous neighborhood exists: every row lies inside it, so the
+        bound never binds.
     """
+    K = log_w.shape[-1]
+    if jnp.shape(prev) != (K, K):
+        raise ValueError(f"feedback_graph: prev must be the previous (K, K) "
+                         f"adjacency, K={K}; got shape {jnp.shape(prev)}")
     with jax.named_scope("round.graph"):
-        mask, iters = _fg(log_w, costs, jnp.asarray(budget), log_w_prev_sums)
+        mask, iters = _fg(log_w, costs, jnp.asarray(budget),
+                          jnp.asarray(prev, bool))
     return (mask, iters) if with_iters else mask
-
-
-def row_log_weight_sums(adj: jnp.ndarray, log_w: jnp.ndarray) -> jnp.ndarray:
-    """log sum of weights of each row's out-neighborhood: (K,).
-
-    Per-row masked logsumexp — the per-row max shift is what keeps this
-    exact at any weight spread (a global-max shift underflows rows far
-    below the leader to log(0)); it runs once per round, so the extra
-    (K, K) ops are not on the greedy loop's per-trip hot path.  The inner
-    sum is a fixed-order ladder (``core.numerics``) so the fused server
-    kernel reproduces it bit-for-bit."""
-    masked = jnp.where(adj, log_w[None, :], _NEG_INF)
-    return ladder_logsumexp(masked, axis=1)
 
 
 # ---------------------------------------------------------------------------
@@ -260,11 +294,16 @@ def row_log_weight_sums(adj: jnp.ndarray, log_w: jnp.ndarray) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 def feedback_graph_np(w: np.ndarray, costs: np.ndarray, budget: float,
-                      w_prev_sums: np.ndarray) -> np.ndarray:
-    """Literal Algorithm 1 on raw (non-log) weights. Returns bool (K, K)."""
+                      prev=None) -> np.ndarray:
+    """Literal Algorithm 1 on raw (non-log) weights. Returns bool (K, K).
+
+    ``prev`` is the previous round's adjacency; ``None`` (the first
+    round) leaves the weight bound out."""
     K = len(w)
     adj = np.zeros((K, K), dtype=bool)
     for k in range(K):
+        w_prev = (np.inf if prev is None
+                  else sum(w[j] for j in range(K) if prev[k][j]))
         out = {k}
         while True:
             cost_sum = sum(costs[j] for j in out)
@@ -273,7 +312,7 @@ def feedback_graph_np(w: np.ndarray, costs: np.ndarray, budget: float,
             elig = [i for i in range(K)
                     if i not in out
                     and cost_sum + costs[i] <= budget
-                    and wsum + w[i] <= w_prev_sums[k] * (1 + 1e-6)]
+                    and wsum + w[i] <= w_prev * (1 + 1e-6)]
             if not elig:
                 break
             # eq. (3)

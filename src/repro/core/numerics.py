@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["ladder_sum", "ladder_logsumexp", "ladder_matvec",
-           "rounding_barrier", "fma_fence"]
+           "rounding_barrier", "fma_fence", "exp", "log", "gumbel"]
 
 
 def rounding_barrier(x: jnp.ndarray) -> jnp.ndarray:
@@ -85,7 +85,8 @@ def fma_fence(x: jnp.ndarray) -> jnp.ndarray:
     return x / rounding_barrier(a / a)
 
 
-def ladder_sum(x: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
+def ladder_sum(x: jnp.ndarray, axis: int = -1, *,
+               fence: bool = True) -> jnp.ndarray:
     """Sum over ``axis`` with a fixed pairwise-halving add tree.
 
     The input rides through ``fma_fence`` first: when the summands are
@@ -98,8 +99,12 @@ def ladder_sum(x: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
     backend pipeline; see ``fma_fence``).  The fence's caveats apply:
     summands must be finite (an ``inf`` comes back NaN), and subnormal
     summands flush to zero under XLA CPU's FTZ environment —
-    deterministically, in every program variant."""
-    x = fma_fence(jnp.moveaxis(x, axis, -1))
+    deterministically, in every program variant.  ``fence=False`` skips
+    the fence where no summand is a product (masked selects of stored
+    values), on hot loops that cannot spare its four ops."""
+    x = jnp.moveaxis(x, axis, -1)
+    if fence:
+        x = fma_fence(x)
     n = x.shape[-1]
     p = 1 << max(n - 1, 0).bit_length()        # next power of two
     if p != n:
@@ -109,6 +114,91 @@ def ladder_sum(x: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
         h = x.shape[-1] // 2
         x = x[..., :h] + x[..., h:]
     return x[..., 0]
+
+
+# ---------------------------------------------------------------------------
+# float32 exp / log to within a few ulp on every backend
+# ---------------------------------------------------------------------------
+#
+# A TPU's own float32 ``exp`` is up to 4.5e-6 off (relative, v5 lite) and
+# its ``log`` up to 1e-4 (absolute): EFL-FG's eq.-(5) mixture and its
+# draw's keys then move by ~1e-4, where the algorithms' comparisons
+# resolve 1e-6 to 1e-5.  The round takes these polynomials on every
+# backend instead, so the host runs what the chip runs.
+
+_LOG2E = 1.4426950408889634
+_LN2_HI = 0.693145751953125          # 16 significant bits: n * hi is exact
+_LN2_LO = 1.4286068203094172e-06
+_SQRT2 = 1.4142135
+_TINY = 1.1754944e-38                # the smallest normal float32
+
+
+def _exp_f32(x):
+    """``exp`` from float32 arithmetic alone: ``x = n ln2 + r`` (Cody-Waite),
+    ``exp(r)`` by its degree-7 Taylor polynomial on ``|r| <= ln2 / 2``
+    (truncation 7e-9), ``2^n`` through the exponent bits: within 1.3 ulp
+    up to ``x = 88`` (10 ulp above, where ``n`` is clipped).  Flushes
+    below the smallest normal, as the TPU does."""
+    x = jnp.asarray(x, jnp.float32)
+    n = jnp.clip(jnp.floor(x * _LOG2E + 0.5), -126.0, 127.0)
+    r = (x - n * _LN2_HI) - n * _LN2_LO
+    p = jnp.float32(1.0 / 5040.0)
+    for c in (1 / 720, 1 / 120, 1 / 24, 1 / 6, 0.5, 1.0, 1.0):
+        p = p * r + c
+    scale = jax.lax.bitcast_convert_type(
+        (n.astype(jnp.int32) + 127) << 23, jnp.float32)
+    out = jnp.where(x < -87.33, 0.0, p * scale)
+    out = jnp.where(x > 88.72, jnp.inf, out)
+    return jnp.where(jnp.isnan(x), x, out)
+
+
+def _log_f32(x):
+    """``log`` from float32 arithmetic alone: ``x = m 2^e`` with ``m`` in
+    ``[sqrt(1/2), sqrt(2))``, ``log m = 2 atanh(s)``, ``s = (m-1)/(m+1)``,
+    by its series to ``s^11`` (``|s| <= 0.172``, truncation 2e-11).
+    Subnormal inputs count as 0, as the TPU flushes them."""
+    x = jnp.asarray(x, jnp.float32)
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    e = ((bits >> 23) & 0xFF) - 127
+    m = jax.lax.bitcast_convert_type((bits & 0x7FFFFF) | 0x3F800000,
+                                     jnp.float32)
+    big = m > _SQRT2
+    m = jnp.where(big, m * 0.5, m)
+    e = jnp.where(big, e + 1, e).astype(jnp.float32)
+    s = (m - 1.0) / (m + 1.0)
+    s2 = s * s
+    p = jnp.float32(1.0 / 11.0)
+    for c in (1 / 9, 1 / 7, 1 / 5, 1 / 3, 1.0):
+        p = p * s2 + c
+    out = e * _LN2_HI + (e * _LN2_LO + 2.0 * s * p)
+    out = jnp.where(x < _TINY, -jnp.inf, out)
+    out = jnp.where(x == jnp.inf, jnp.inf, out)
+    return jnp.where(jnp.isnan(x) | (x < 0), jnp.nan, out)
+
+
+def _on_vector(f, x):
+    """``f`` on ``x``, a scalar lifted to a 1-vector first: Mosaic bitcasts
+    vectors only."""
+    x = jnp.asarray(x, jnp.float32)
+    return f(jnp.broadcast_to(x, (1,)))[0] if x.ndim == 0 else f(x)
+
+
+def exp(x: jnp.ndarray) -> jnp.ndarray:
+    """``jnp.exp``, to within a few float32 ulp on every backend."""
+    return _on_vector(_exp_f32, x)
+
+
+def log(x: jnp.ndarray) -> jnp.ndarray:
+    """``jnp.log``, to within a few float32 ulp on every backend."""
+    return _on_vector(_log_f32, x)
+
+
+def gumbel(key, shape) -> jnp.ndarray:
+    """``jax.random.gumbel(key, shape, float32)`` with ``log`` above: the
+    same uniforms on ``[tiny, 1)``."""
+    u = jax.random.uniform(key, shape, jnp.float32,
+                           minval=jnp.finfo(jnp.float32).tiny, maxval=1.0)
+    return -log(-log(u))
 
 
 def ladder_logsumexp(x: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
@@ -121,8 +211,8 @@ def ladder_logsumexp(x: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
     """
     m = jnp.max(x, axis=axis, keepdims=True)
     m = jnp.where(jnp.isfinite(m), m, 0.0)     # all-masked-row guard
-    s = ladder_sum(jnp.exp(x - m), axis=axis)
-    return jnp.log(s) + jnp.squeeze(m, axis=axis)
+    s = ladder_sum(exp(x - m), axis=axis)
+    return log(s) + jnp.squeeze(m, axis=axis)
 
 
 def ladder_matvec(v: jnp.ndarray, m: jnp.ndarray) -> jnp.ndarray:

@@ -10,8 +10,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .numerics import (fma_fence, ladder_logsumexp, ladder_matvec,
-                       ladder_sum)
+from .numerics import (exp, fma_fence, gumbel, ladder_logsumexp,
+                       ladder_matvec, ladder_sum, log)
 
 __all__ = [
     "pmf",
@@ -25,7 +25,7 @@ __all__ = [
 
 def pmf(log_u: jnp.ndarray, dom: jnp.ndarray, xi: jnp.ndarray) -> jnp.ndarray:
     """Eq. (4): p_k = (1-xi) u_k / U + xi / |D| * I(k in D)."""
-    exploit = jnp.exp(log_u - ladder_logsumexp(log_u))
+    exploit = exp(log_u - ladder_logsumexp(log_u))
     dsize = jnp.sum(dom)
     explore = dom.astype(exploit.dtype) / jnp.maximum(dsize, 1)
     # the fences pin the two products to round before the mixture add:
@@ -39,14 +39,16 @@ def pmf(log_u: jnp.ndarray, dom: jnp.ndarray, xi: jnp.ndarray) -> jnp.ndarray:
 
 
 def draw_node(key: jax.Array, p: jnp.ndarray) -> jnp.ndarray:
-    """Draw I_t ~ p_t."""
-    return jax.random.categorical(key, jnp.log(jnp.maximum(p, 1e-38)))
+    """Draw I_t ~ p_t by the Gumbel-max trick, as
+    ``jax.random.categorical(key, log p)`` does, with ``numerics.log``
+    for the noise and the logits."""
+    return jnp.argmax(gumbel(key, p.shape) + log(jnp.maximum(p, 1e-38)))
 
 
 def ensemble_mix_weights(log_w: jnp.ndarray, sel: jnp.ndarray) -> jnp.ndarray:
     """Eq. (5) mixture weights: w_k / W_t restricted to the selected set."""
     masked = jnp.where(sel, log_w, -jnp.inf)
-    return jnp.exp(masked - ladder_logsumexp(masked))
+    return exp(masked - ladder_logsumexp(masked))
 
 
 def observation_probs(adj: jnp.ndarray, p: jnp.ndarray) -> jnp.ndarray:

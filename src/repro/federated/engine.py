@@ -476,7 +476,7 @@ def run_batch(algo: str, preds, y, costs, T: int, cfg: SimConfig,
     if len(budgets) != n:
         raise ValueError(f"run_batch: {n} seeds but {len(budgets)} budgets "
                          "— the batch axis is flat (one pair per lane)")
-    with obs.span("engine.run_batch", items=n):
+    with obs.span("engine.run_batch", items=n) as call:
         with obs.span("engine.prepare"):
             preds = jnp.asarray(preds, jnp.float32)
             y = jnp.asarray(y, jnp.float32)
@@ -527,6 +527,10 @@ def run_batch(algo: str, preds, y, costs, T: int, cfg: SimConfig,
                         outs[k][idx] = v
         with obs.span("engine.unpack"):
             outs = _host_outs(outs, preds.shape[0])
+            if algo == "eflfg":
+                iters = outs["graph_iters"]
+                _count_graph_steps(call, [iters] if sharded or buckets is None
+                                   else [iters[idx] for idx in buckets])
             if scale is None:
                 thresh = [budgets[i] for i in range(n)]
             elif scale.ndim == 1:       # one shared scenario: (T,) factors
@@ -721,9 +725,8 @@ class SweepResult:
         equivalent vmap dispatch (lockstep is per shard there).  Zero for
         FedBoost sweeps (no graph) and single-lane sweeps.
         """
-        it = self.graph_iters.reshape(-1, self.graph_iters.shape[-1])
-        return int((it.max(axis=0, keepdims=True)
-                    - it).astype(np.int64).sum())
+        appends, trips = _graph_steps(self.graph_iters)
+        return trips - appends
 
     def identical_fields(self, other: "SweepResult") -> dict:
         """Per-field exact-equality map vs another sweep's results."""
@@ -733,6 +736,24 @@ class SweepResult:
     def identical_to(self, other: "SweepResult") -> bool:
         """True iff every ``FIELDS`` array matches ``other`` bit-for-bit."""
         return all(self.identical_fields(other).values())
+
+
+def _graph_steps(iters) -> tuple:
+    """``(appends, trips)`` of one lockstep group from its stacked
+    ``graph_iters`` (lanes..., T): the lanes' own productive append
+    steps, and the steps the group ran — each round as many as its
+    slowest lane, in every lane."""
+    it = np.asarray(iters).reshape(-1, iters.shape[-1]).astype(np.int64)
+    return int(it.sum()), int(it.max(axis=0).sum()) * it.shape[0]
+
+
+def _count_graph_steps(call, groups) -> None:
+    """Record the graph builder's lockstep on the call's span:
+    ``<call>.graph_appends`` and ``<call>.graph_trips`` over the lockstep
+    groups ``groups`` (stacked ``graph_iters``, one array per group)."""
+    steps = [_graph_steps(g) for g in groups]
+    call.count("graph_appends", sum(a for a, _ in steps))
+    call.count("graph_trips", sum(t for _, t in steps))
 
 
 def _flatten_configs(keys, budgets, default_budget):
@@ -802,7 +823,7 @@ def run_sweep_sharded(algo: str, preds, y, costs, T: int, cfg: SimConfig,
     """
     seeds = list(seeds)
     n_cfg = len(seeds) * (1 if budgets is None else len(budgets))
-    with obs.span("engine.run_sweep", items=n_cfg):
+    with obs.span("engine.run_sweep", items=n_cfg) as call:
         with obs.span("engine.prepare"):
             preds = jnp.asarray(preds, jnp.float32)
             y = jnp.asarray(y, jnp.float32)
@@ -836,8 +857,11 @@ def run_sweep_sharded(algo: str, preds, y, costs, T: int, cfg: SimConfig,
             if grid_shape is not None:
                 outs = jax.tree.map(
                     lambda a: a.reshape(grid_shape + a.shape[1:]), outs)
-            return SweepResult(outs, seeds, budgets_arr, T, sharded=True,
-                               budget_scale=scale)
+            res = SweepResult(outs, seeds, budgets_arr, T, sharded=True,
+                              budget_scale=scale)
+            if algo == "eflfg":
+                _count_graph_steps(call, [res.graph_iters])
+            return res
 
 
 def _dispatch_sharded(cfg: SimConfig, n_cfg: int) -> bool:
@@ -889,7 +913,7 @@ def run_sweep(algo: str, preds, y, costs, T: int, cfg: SimConfig,
     if mesh is not None or _dispatch_sharded(cfg, n_cfg):
         return run_sweep_sharded(algo, preds, y, costs, T, cfg, seeds,
                                  budgets, mesh=mesh, scenario=scenario)
-    with obs.span("engine.run_sweep", items=n_cfg):
+    with obs.span("engine.run_sweep", items=n_cfg) as call:
         with obs.span("engine.prepare"):
             preds = jnp.asarray(preds, jnp.float32)
             y = jnp.asarray(y, jnp.float32)
@@ -912,5 +936,8 @@ def run_sweep(algo: str, preds, y, costs, T: int, cfg: SimConfig,
                 args += (sched,)
         outs = _run_program(fn, *args)
         with obs.span("engine.unpack"):
-            return SweepResult(_host_outs(outs, preds.shape[0]), seeds,
-                               budgets_arr, T, budget_scale=scale)
+            res = SweepResult(_host_outs(outs, preds.shape[0]), seeds,
+                              budgets_arr, T, budget_scale=scale)
+            if algo == "eflfg":
+                _count_graph_steps(call, [res.graph_iters])
+            return res

@@ -316,10 +316,9 @@ def _make_evaluate(algo: str, fused: bool, preds, y, cfg: SimConfig,
     of the round body that differs between the fused Pallas kernel and
     the unfused ops.
 
-    EFL-FG fused recomputes the eq.-(5) log-space mixture in-kernel from
-    ``plan.log_w`` (no gradient needed); FedBoost's plan mixture is
-    already on the simplex, so the kernel applies it directly
-    (``weighting="none"``) and emits the SGD gradient.
+    Both algorithms hand the kernel the plan's mixture: EFL-FG's eq.-(5)
+    softmax (no gradient needed) and FedBoost's renormalized alpha (the
+    kernel also emits its SGD gradient).
 
     ``ext`` optionally supplies a precomputed ``extend_stream`` result —
     the reference loop passes it so the loop-invariant extension is built
@@ -333,9 +332,8 @@ def _make_evaluate(algo: str, fused: bool, preds, y, cfg: SimConfig,
             def evaluate(plan, cursor, n_t, sched=None):
                 active, shift = sched if sched is not None else (None, None)
                 ev = client_eval_ops.client_eval(
-                    preds_ext, y_ext, cursor, n_t, plan.log_w, plan.sel,
-                    loss_scale=cfg.loss_scale, window=W, weighting="log",
-                    with_grad=False, active=active, shift=shift)
+                    preds_ext, y_ext, cursor, n_t, plan.mix,
+                    loss_scale=cfg.loss_scale, window=W, with_grad=False, active=active, shift=shift)
                 return ev.ens_sq_mean, ev.ens_norm, ev.model_losses, None
         else:
             def evaluate(plan, cursor, n_t, sched=None):
@@ -347,11 +345,10 @@ def _make_evaluate(algo: str, fused: bool, preds, y, cfg: SimConfig,
         if fused:
             def evaluate(plan, cursor, n_t, sched=None):
                 active, shift = sched if sched is not None else (None, None)
-                sel, _pi, mix, _cost = plan
+                _sel, _pi, mix, _cost = plan
                 ev = client_eval_ops.client_eval(
-                    preds_ext, y_ext, cursor, n_t, mix, sel,
-                    loss_scale=cfg.loss_scale, window=W, weighting="none",
-                    with_grad=True, active=active, shift=shift)
+                    preds_ext, y_ext, cursor, n_t, mix,
+                    loss_scale=cfg.loss_scale, window=W, with_grad=True, active=active, shift=shift)
                 return ev.ens_sq_mean, ev.ens_norm, ev.model_losses, ev.grad
         else:
             def evaluate(plan, cursor, n_t, sched=None):

@@ -1,10 +1,10 @@
 """Pallas TPU kernel: fused per-round client evaluation.
 
 One launch per simulation round fuses the whole client-side exchange —
-window gather, eq.-(5) mixture weighting, the ensemble/per-model
-squared-loss accumulators, and the FedBoost mixture gradient — into a
-single pass over the round's (K, W) prediction tile, replacing the ~6
-small ops the unfused round body dispatches per round.
+window gather, the ensemble prediction under the plan's mixture, the
+ensemble/per-model squared-loss accumulators, and the FedBoost mixture
+gradient — into a single pass over the round's (K, W) prediction tile,
+replacing the ~6 small ops the unfused round body dispatches per round.
 
 TPU mapping: the extended stream (K, extended_width) and targets ride
 in as whole-array VMEM operands — at the paper's largest stream (K=22,
@@ -26,7 +26,7 @@ than one launch per sweep lane.
 
 Numerics: float32 throughout, formula-for-formula identical to the
 unfused path (`simulation.client_window_losses`,
-``simulation.fedboost_window_grad``, ``policy.ensemble_mix_weights``);
+``simulation.fedboost_window_grad``);
 interpret mode on CPU executes the same XLA ops, so fused-vs-unfused
 round trajectories agree to float32 rounding (empirically bit-equal
 selection masks on the paper config — pinned by the benchmark's
@@ -46,19 +46,17 @@ from jax.experimental import pallas as pl
 from repro.core.numerics import ladder_matvec, ladder_sum
 
 from ..platform import prepare_mosaic
-from .ref import LANES, WEIGHTINGS, mix_weights_ref, window_frame
+from .ref import LANES, window_frame
 
 __all__ = ["client_eval_pallas"]
 
 
-def _client_eval_kernel(preds_ref, y_ref, cursor_ref, nt_ref, w_ref,
-                        sel_ref, active_ref, shift_ref, mix_ref, scal_ref,
-                        ml_ref, grad_ref,
-                        *, loss_scale: float, window: int, weighting: str,
+def _client_eval_kernel(preds_ref, y_ref, cursor_ref, nt_ref, mix_ref,
+                        active_ref, shift_ref, scal_ref, ml_ref, grad_ref,
+                        *, loss_scale: float, window: int,
                         with_grad: bool, interpret: bool):
     # preds_ref: (K, L); y_ref: (1, L); cursor/nt: (1, 1) int32;
-    # w_ref/sel_ref: (1, K); outputs: mix (1, K), scal (1, 2), ml/grad
-    # (K, 1).  active_ref (1, W) int32 / shift_ref (1, 1) f32 are the
+    # mix_ref: (1, K); outputs: scal (1, 2), ml/grad (K, 1).  active_ref (1, W) int32 / shift_ref (1, 1) f32 are the
     # optional per-round schedule operands (repro.scenarios) — ``None`` on
     # the stationary path, which then traces exactly the pre-scenario ops.
     cursor = cursor_ref[0, 0]
@@ -94,13 +92,7 @@ def _client_eval_kernel(preds_ref, y_ref, cursor_ref, nt_ref, w_ref,
             return ladder_sum(x, axis=1)[:, None]
         return jnp.sum(x, axis=1, keepdims=True)
 
-    w = w_ref[...]                                      # (1, K)
-    sel = sel_ref[...] != 0
-    # the one eq.-(5) implementation: pure jnp, reduces over all axes, so
-    # it applies unchanged to the kernel's (1, K) operands — keeping the
-    # fused path formula-identical to the oracle by construction
-    mix = mix_weights_ref(w, sel, weighting)
-    mix_ref[...] = mix.astype(mix_ref.dtype)
+    mix = mix_ref[...]                                  # (1, K)
 
     sq = (pw - yw) ** 2                                 # (K, W) broadcast
     ml_ref[...] = lane_sum(
@@ -137,21 +129,20 @@ def _client_eval_kernel(preds_ref, y_ref, cursor_ref, nt_ref, w_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("loss_scale", "window",
-                                             "weighting", "with_grad",
-                                             "interpret"))
+                                             "with_grad", "interpret"))
 def client_eval_pallas(preds_ext: jnp.ndarray, y_ext: jnp.ndarray,
                        cursor: jnp.ndarray, n_t: jnp.ndarray,
-                       w: jnp.ndarray, sel: jnp.ndarray, *,
-                       loss_scale: float, window: int,
-                       weighting: str = "log", with_grad: bool = True,
-                       interpret: bool = True, active=None, shift=None):
+                       mix: jnp.ndarray, *, loss_scale: float, window: int,
+                       with_grad: bool = True, interpret: bool = True,
+                       active=None, shift=None):
     """Fused client-eval launch.
 
     ``preds_ext``: (K, extended_width) f32; ``y_ext``: (extended_width,)
     f32 (see ``ref.extend_stream``); ``cursor``/``n_t``: int32 scalars;
-    ``w``/``sel``: (K,).  Returns ``(mix, ens_sq_mean, ens_norm,
-    model_losses, grad)`` with ``grad = None`` when ``with_grad`` is off
-    (the EFL-FG path needs no mixture gradient).
+    ``mix``: (K,), the round's mixture, zero off the transmit set.
+    Returns ``(ens_sq_mean, ens_norm, model_losses, grad)`` with
+    ``grad = None`` when ``with_grad`` is off (the EFL-FG path needs no
+    mixture gradient).
 
     ``active`` ((window,) bool) and ``shift`` (scalar f32) are the
     optional schedule operands of the scenario path
@@ -159,8 +150,6 @@ def client_eval_pallas(preds_ext: jnp.ndarray, y_ext: jnp.ndarray,
     exactly the pre-scenario operand list, so stationary programs are
     untouched.
     """
-    if weighting not in WEIGHTINGS:
-        raise ValueError(f"unknown weighting {weighting!r}")
     if (active is None) != (shift is None):
         raise ValueError("schedule operands come together: pass both "
                          "active and shift, or neither")
@@ -169,34 +158,31 @@ def client_eval_pallas(preds_ext: jnp.ndarray, y_ext: jnp.ndarray,
     scheduled = active is not None
     K, SW = preds_ext.shape
     kern = functools.partial(_client_eval_kernel, loss_scale=loss_scale,
-                             window=window, weighting=weighting,
-                             with_grad=with_grad, interpret=interpret)
+                             window=window, with_grad=with_grad,
+                             interpret=interpret)
     kern = _adapt_refs(kern, with_grad=with_grad, scheduled=scheduled)
     full = lambda *_: (0, 0)
     out_shape = [
-        jax.ShapeDtypeStruct((1, K), jnp.float32),   # mix
         jax.ShapeDtypeStruct((1, 2), jnp.float32),   # [ens_sq_mean, ens_norm]
         jax.ShapeDtypeStruct((K, 1), jnp.float32),   # model_losses
         jax.ShapeDtypeStruct((K, 1), jnp.float32),   # grad
     ]
-    out_specs = [pl.BlockSpec((1, K), full), pl.BlockSpec((1, 2), full),
-                 pl.BlockSpec((K, 1), full), pl.BlockSpec((K, 1), full)]
+    out_specs = [pl.BlockSpec((1, 2), full), pl.BlockSpec((K, 1), full),
+                 pl.BlockSpec((K, 1), full)]
     if not with_grad:
-        out_shape, out_specs = out_shape[:3], out_specs[:3]
+        out_shape, out_specs = out_shape[:2], out_specs[:2]
     in_specs = [
         pl.BlockSpec((K, SW), full),
         pl.BlockSpec((1, SW), full),
         pl.BlockSpec((1, 1), full),
         pl.BlockSpec((1, 1), full),
         pl.BlockSpec((1, K), full),
-        pl.BlockSpec((1, K), full),
     ]
     operands = [preds_ext.astype(jnp.float32),
                 y_ext.astype(jnp.float32).reshape(1, SW),
                 jnp.asarray(cursor, jnp.int32).reshape(1, 1),
                 jnp.asarray(n_t, jnp.int32).reshape(1, 1),
-                jnp.asarray(w, jnp.float32).reshape(1, K),
-                jnp.asarray(sel, jnp.int32).reshape(1, K)]
+                jnp.asarray(mix, jnp.float32).reshape(1, K)]
     if scheduled:
         active = jnp.asarray(active, jnp.int32)
         if not interpret:
@@ -216,24 +202,23 @@ def client_eval_pallas(preds_ext: jnp.ndarray, y_ext: jnp.ndarray,
         out_shape=out_shape,
         interpret=interpret,
     )(*operands)
-    mix, scal, ml = outs[0][0], outs[1], outs[2][:, 0]
-    grad = outs[3][:, 0] if with_grad else None
-    return mix, scal[0, 0], scal[0, 1], ml, grad
+    scal, ml = outs[0], outs[1][:, 0]
+    grad = outs[2][:, 0] if with_grad else None
+    return scal[0, 0], scal[0, 1], ml, grad
 
 
 def _adapt_refs(kern, with_grad: bool, scheduled: bool):
-    """Adapt the full 12-ref kernel body to the launch's actual ref list
+    """Adapt the full 10-ref kernel body to the launch's actual ref list
     (the schedule operands and the grad output are both optional)."""
     def wrapped(*refs):
         refs = list(refs)
-        ins, i = refs[:6], 6
+        ins, i = refs[:5], 5
         active_ref = shift_ref = None
         if scheduled:
-            active_ref, shift_ref = refs[6], refs[7]
-            i = 8
-        mix_ref, scal_ref, ml_ref = refs[i:i + 3]
-        grad_ref = refs[i + 3] if with_grad else None
-        kern(*ins, active_ref, shift_ref, mix_ref, scal_ref, ml_ref,
-             grad_ref)
+            active_ref, shift_ref = refs[5], refs[6]
+            i = 7
+        scal_ref, ml_ref = refs[i:i + 2]
+        grad_ref = refs[i + 2] if with_grad else None
+        kern(*ins, active_ref, shift_ref, scal_ref, ml_ref, grad_ref)
         return
     return wrapped

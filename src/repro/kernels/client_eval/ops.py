@@ -22,10 +22,9 @@ __all__ = ["client_eval", "extend_stream", "ClientEvalOut"]
 
 
 def client_eval(preds_ext: jnp.ndarray, y_ext: jnp.ndarray,
-                cursor: jnp.ndarray, n_t: jnp.ndarray,
-                w: jnp.ndarray, sel: jnp.ndarray, *,
-                loss_scale: float, window: int, weighting: str = "log",
-                with_grad: bool = True, interpret: bool | None = None,
+                cursor: jnp.ndarray, n_t: jnp.ndarray, mix: jnp.ndarray, *,
+                loss_scale: float, window: int, with_grad: bool = True,
+                interpret: bool | None = None,
                 active=None, shift=None) -> ClientEvalOut:
     """One fused round of client-side evaluation (see ``ref.client_eval_ref``
     for exact semantics).  ``grad`` is zeros-shaped ``None``-free only when
@@ -37,8 +36,8 @@ def client_eval(preds_ext: jnp.ndarray, y_ext: jnp.ndarray,
     """
     if interpret is None:
         interpret = interpret_mode()
-    mix, ens_sq_mean, ens_norm, model_losses, grad = client_eval_pallas(
-        preds_ext, y_ext, cursor, n_t, w, sel, loss_scale=loss_scale,
-        window=window, weighting=weighting, with_grad=with_grad,
-        interpret=interpret, active=active, shift=shift)
-    return ClientEvalOut(mix, ens_sq_mean, ens_norm, model_losses, grad)
+    ens_sq_mean, ens_norm, model_losses, grad = client_eval_pallas(
+        preds_ext, y_ext, cursor, n_t, mix, loss_scale=loss_scale,
+        window=window, with_grad=with_grad, interpret=interpret,
+        active=active, shift=shift)
+    return ClientEvalOut(ens_sq_mean, ens_norm, model_losses, grad)

@@ -5,16 +5,15 @@ round's (K, W) prediction window:
 
 * gather the online window ``preds[:, (cursor + 0..W-1) % n_stream]``
   (realized wrap-free on a W-extended stream, see ``extend_stream``),
-* eq. (5) mixture weighting (log-space softmax over the selected set for
-  EFL-FG, masked renormalization for FedBoost's alpha, or a passthrough
-  when the caller already holds the mixture),
+* the ensemble prediction under the plan's mixture (EFL-FG's eq.-(5)
+  softmax or FedBoost's renormalized alpha, zero off the transmit set),
 * ``client_window_losses`` — the ensemble/per-model squared-loss
   accumulators with the (a2) normalization ``min(sq / loss_scale, 1)``,
 * ``fedboost_window_grad`` — g_k = 2/n_t sum_i (yhat - y_i) f_k(x_i).
 
 The formulas are kept call-for-call identical to the unfused path
 (`repro.federated.simulation.client_window_losses` /
-``fedboost_window_grad`` + `repro.core.policy.ensemble_mix_weights`) so
+``fedboost_window_grad``) so
 the fused round body reproduces the unfused trajectories; the Pallas
 kernel is tested against this oracle and against independent float64
 NumPy implementations in ``tests/test_client_eval.py``.
@@ -32,42 +31,17 @@ from typing import NamedTuple
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.numerics import ladder_logsumexp, ladder_matvec, ladder_sum
+from repro.core.numerics import ladder_matvec, ladder_sum
 
-__all__ = ["ClientEvalOut", "WEIGHTINGS", "mix_weights_ref",
-           "client_eval_ref", "extend_stream", "extended_width",
-           "window_frame", "LANES"]
-
-WEIGHTINGS = ("log", "linear", "none")
+__all__ = ["ClientEvalOut", "client_eval_ref", "extend_stream",
+           "extended_width", "window_frame", "LANES"]
 
 
 class ClientEvalOut(NamedTuple):
-    mix: jnp.ndarray           # (K,) eq.-(5) mixture actually applied
     ens_sq_mean: jnp.ndarray   # scalar, mean ensemble sq error over n_t
     ens_norm: jnp.ndarray      # scalar, sum of normalized ensemble losses
     model_losses: jnp.ndarray  # (K,) sum of normalized per-model losses
     grad: jnp.ndarray          # (K,) FedBoost mixture gradient
-
-
-def mix_weights_ref(w: jnp.ndarray, sel: jnp.ndarray,
-                    weighting: str) -> jnp.ndarray:
-    """The three mixture rules the round bodies need.
-
-    ``log``:    w = log-weights; eq. (5) softmax over the selected set
-                (identical to ``policy.ensemble_mix_weights``).
-    ``linear``: w = simplex weights (FedBoost alpha); masked renormalize
-                (identical to ``fedboost_plan``'s mixing).
-    ``none``:   w already *is* the mixture; passthrough.
-    """
-    if weighting == "log":
-        masked = jnp.where(sel, w, -jnp.inf)
-        return jnp.exp(masked - ladder_logsumexp(masked))
-    if weighting == "linear":
-        masked = jnp.where(sel, w, 0.0)
-        return masked / jnp.maximum(ladder_sum(masked), 1e-12)
-    if weighting == "none":
-        return w
-    raise ValueError(f"unknown weighting {weighting!r}")
 
 
 LANES = 128   # TPU vector lane width: compiled lane-dim loads start here
@@ -111,16 +85,15 @@ def extend_stream(preds: jnp.ndarray, y: jnp.ndarray, window: int):
 
 def client_eval_ref(preds_ext: jnp.ndarray, y_ext: jnp.ndarray,
                     cursor: jnp.ndarray, n_t: jnp.ndarray,
-                    w: jnp.ndarray, sel: jnp.ndarray,
-                    loss_scale: float, window: int,
-                    weighting: str = "log", active=None,
-                    shift=None) -> ClientEvalOut:
+                    mix: jnp.ndarray, loss_scale: float, window: int,
+                    active=None, shift=None) -> ClientEvalOut:
     """Single-pass jnp reference of the fused round evaluation.
 
     ``preds_ext``: (K, extended_width) extended predictions;
     ``y_ext``: (extended_width,) extended targets (see
-    ``extend_stream``); ``cursor``/``n_t``: int32 scalars; ``w``/``sel``:
-    (K,) weights + transmit mask.  Returns ``ClientEvalOut``.
+    ``extend_stream``); ``cursor``/``n_t``: int32 scalars; ``mix``: (K,)
+    the round's mixture, zero off the transmit set.  Returns
+    ``ClientEvalOut``.
 
     ``active``/``shift`` are the optional per-round schedule operands
     (``repro.scenarios``): a (window,) availability mask ANDed into the
@@ -137,7 +110,7 @@ def client_eval_ref(preds_ext: jnp.ndarray, y_ext: jnp.ndarray,
     y_cl = lax.dynamic_slice(y_ext, (cursor,), (window,))
     if shift is not None:
         y_cl = y_cl + shift
-    mix = mix_weights_ref(w, sel, weighting).astype(p_cl.dtype)
+    mix = mix.astype(p_cl.dtype)
     sq = (p_cl - y_cl[None, :]) ** 2
     model_losses = ladder_sum(
         jnp.where(cmask[None, :], jnp.minimum(sq / loss_scale, 1.0), 0.0),
@@ -152,4 +125,4 @@ def client_eval_ref(preds_ext: jnp.ndarray, y_ext: jnp.ndarray,
     ens_norm = ladder_sum(jnp.minimum(ens_sq / loss_scale, 1.0))
     resid = jnp.where(cmask, yhat - y_cl, 0.0)
     grad = (2.0 / nf) * ladder_sum(p_cl * resid[None, :], axis=1)
-    return ClientEvalOut(mix, ens_sq_mean, ens_norm, model_losses, grad)
+    return ClientEvalOut(ens_sq_mean, ens_norm, model_losses, grad)

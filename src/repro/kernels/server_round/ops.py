@@ -9,17 +9,15 @@ on CPU, compiled Pallas on TPU, an error elsewhere), returning the same pytrees 
 ``make_eflfg_scan_body`` can swap the server implementation behind
 ``SimConfig.use_fused_server`` without touching the round structure.
 The PRNG split stays outside the kernel: the node draw consumes
-``jax.random.gumbel(key, (K,), float32)``, which reproduces
-``policy.draw_node``'s ``jax.random.categorical`` bit-for-bit (see
-``ref``).
+``numerics.gumbel(key, (K,))``, the noise of ``policy.draw_node``'s
+Gumbel-max draw, bit for bit (see ``ref``).
 """
 
 from __future__ import annotations
 
 from typing import Callable, NamedTuple, Optional
 
-import jax
-import jax.numpy as jnp
+from repro.core import numerics
 
 from ..platform import interpret_mode
 from .kernel import server_plan_pallas, server_update_pallas
@@ -29,14 +27,14 @@ __all__ = ["server_plan", "server_update", "fused_server_round",
            "FusedServerRound"]
 
 
-def server_plan(log_w, log_u, log_w_prev_sums, costs, budget, gumbel, xi,
+def server_plan(log_w, log_u, prev_adj, costs, budget, gumbel, xi,
                 *, interpret: Optional[bool] = None) -> ServerPlanOut:
     """One fused planning launch (see ``ref.server_plan_ref`` for exact
     semantics).  Masks come back as bool."""
     if interpret is None:
         interpret = interpret_mode()
     adj, dom, p, drawn, sel, mix, cost, iters = server_plan_pallas(
-        log_w, log_u, log_w_prev_sums, costs, budget, gumbel, xi,
+        log_w, log_u, prev_adj, costs, budget, gumbel, xi,
         interpret=interpret)
     return ServerPlanOut(adj != 0, dom != 0, p, drawn, sel != 0, mix,
                          cost, iters)
@@ -48,10 +46,10 @@ def server_update(adj, p, sel, drawn, model_losses, ens_loss, log_w,
     """One fused update launch (see ``ref.server_update_ref``)."""
     if interpret is None:
         interpret = interpret_mode()
-    new_w, new_u, prev = server_update_pallas(
+    new_w, new_u = server_update_pallas(
         adj, p, sel, drawn, model_losses, ens_loss, log_w, log_u, eta,
         interpret=interpret)
-    return ServerUpdateOut(new_w, new_u, prev)
+    return ServerUpdateOut(new_w, new_u)
 
 
 class FusedServerRound(NamedTuple):
@@ -67,19 +65,17 @@ def fused_server_round(interpret: Optional[bool] = None) -> FusedServerRound:
 
     def plan(state, key, costs, budget, xi):
         K = state.log_w.shape[0]
-        gumbel = jax.random.gumbel(key, (K,), jnp.float32)
-        out = server_plan(state.log_w, state.log_u, state.log_w_prev_sums,
+        gumbel = numerics.gumbel(key, (K,))
+        out = server_plan(state.log_w, state.log_u, state.prev_adj,
                           costs, budget, gumbel, xi, interpret=interpret)
         return EFLFGRoundOut(out.adj, out.dom, out.p, out.drawn, out.sel,
-                             out.mix, out.round_cost, state.log_w,
-                             out.graph_iters)
+                             out.mix, out.round_cost, out.graph_iters)
 
     def update(state, plan_out, model_losses, ens_loss, eta):
         out = server_update(plan_out.adj, plan_out.p, plan_out.sel,
                             plan_out.drawn, model_losses, ens_loss,
                             state.log_w, state.log_u, eta,
                             interpret=interpret)
-        return EFLFGState(out.log_w, out.log_u, out.log_w_prev_sums,
-                          state.t + 1)
+        return EFLFGState(out.log_w, out.log_u, plan_out.adj, state.t + 1)
 
     return FusedServerRound(plan, update)

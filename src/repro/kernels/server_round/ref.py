@@ -7,9 +7,9 @@ the client exchange:
   dominating set, the eq.-(4) PMF, the I_t draw, the transmit set
   S_t = N_out(I_t), the eq.-(5) mixture, and the round cost;
 * **update** (after client losses return): eq.-(7) observation
-  probabilities, the eq.-(6)/(8) importance-sampled estimates, both
-  eq.-(9) exponential-weight updates, and the eq.-(2) neighborhood
-  weight sums for the next round's constraint.
+  probabilities, the eq.-(6)/(8) importance-sampled estimates and both
+  eq.-(9) exponential-weight updates (the next round's eq.-(2) bound
+  reads the plan's graph, so the update computes nothing for it).
 
 The reference here composes the *actual* core implementations
 (``repro.core.graph`` / ``domset`` / ``policy``), so it is bit-equal to
@@ -34,10 +34,9 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.core import policy
-from repro.core.graph import (feedback_graph, feedback_graph_np,
-                              row_log_weight_sums)
+from repro.core.graph import feedback_graph, feedback_graph_np
 from repro.core.domset import dominating_set, dominating_set_np
-from repro.core.numerics import ladder_sum
+from repro.core.numerics import ladder_sum, log
 
 __all__ = ["ServerPlanOut", "ServerUpdateOut", "server_plan_ref",
            "server_update_ref", "server_round_np"]
@@ -57,18 +56,17 @@ class ServerPlanOut(NamedTuple):
 class ServerUpdateOut(NamedTuple):
     log_w: jnp.ndarray           # (K,) updated model confidences
     log_u: jnp.ndarray           # (K,) updated node confidences
-    log_w_prev_sums: jnp.ndarray  # (K,) next round's eq.-(2) sums
 
 
-def server_plan_ref(log_w, log_u, log_w_prev_sums, costs, budget,
+def server_plan_ref(log_w, log_u, prev_adj, costs, budget,
                     gumbel, xi) -> ServerPlanOut:
     """Planning half, formula-identical to ``eflfg.plan_round`` with the
     draw refactored to ``argmax(gumbel + log p)`` (module docstring)."""
-    adj, iters = feedback_graph(log_w, costs, budget, log_w_prev_sums,
+    adj, iters = feedback_graph(log_w, costs, budget, prev_adj,
                                 with_iters=True)
     dom = dominating_set(adj)
     p = policy.pmf(log_u, dom, xi)
-    drawn = jnp.argmax(gumbel + jnp.log(jnp.maximum(p, 1e-38)))
+    drawn = jnp.argmax(gumbel + log(jnp.maximum(p, 1e-38)))
     sel = adj[drawn]
     mix = policy.ensemble_mix_weights(log_w, sel)
     round_cost = ladder_sum(jnp.where(sel, costs, 0.0))
@@ -83,10 +81,10 @@ def server_update_ref(adj, p, sel, drawn, model_losses, ens_loss,
                                             drawn, p, q)
     new_w = policy.exp_weight_update(log_w, eta, ell)
     new_u = policy.exp_weight_update(log_u, eta, ell_hat)
-    return ServerUpdateOut(new_w, new_u, row_log_weight_sums(adj, new_w))
+    return ServerUpdateOut(new_w, new_u)
 
 
-def server_round_np(log_w, log_u, log_w_prev_sums, costs, budget, gumbel,
+def server_round_np(log_w, log_u, prev_adj, costs, budget, gumbel,
                     xi, model_losses, ens_loss, eta):
     """Independent float64 NumPy transcription of the full server round
     (plan + update), for the oracle tests.  Same argument convention as
@@ -95,15 +93,11 @@ def server_round_np(log_w, log_u, log_w_prev_sums, costs, budget, gumbel,
     """
     log_w = np.asarray(log_w, np.float64)
     log_u = np.asarray(log_u, np.float64)
-    lps = np.asarray(log_w_prev_sums, np.float64)
     costs = np.asarray(costs, np.float64)
     gumbel = np.asarray(gumbel, np.float64)
     K = log_w.shape[0]
-    # exp space is safe in float64 at test spreads; the 1e30 round-1
-    # sentinel clips to a still-overflowing-to-inf finite exponent
-    w = np.exp(log_w)
-    w_prev = np.exp(np.clip(lps, None, 700.0))
-    adj = feedback_graph_np(w, costs, float(budget), w_prev)
+    w = np.exp(log_w)       # exp space is safe in float64 at test spreads
+    adj = feedback_graph_np(w, costs, float(budget), np.asarray(prev_adj))
     dom = dominating_set_np(adj)
     u = np.exp(log_u - log_u.max())
     exploit = u / u.sum()
@@ -125,7 +119,4 @@ def server_round_np(log_w, log_u, log_w_prev_sums, costs, budget, gumbel,
                        float(ens_loss) / np.maximum(p, 1e-12), 0.0)
     new_w = log_w - eta * ell
     new_u = log_u - eta * ell_hat
-    row = np.where(adj, new_w[None, :], -np.inf)
-    m = row.max(axis=1)
-    prev = m + np.log(np.exp(row - m[:, None]).sum(axis=1))
-    return plan, ServerUpdateOut(new_w, new_u, prev)
+    return plan, ServerUpdateOut(new_w, new_u)

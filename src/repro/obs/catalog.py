@@ -69,17 +69,30 @@ _ENGINE_CALL = {
     "engine.unpack": "transmit-set words to bool masks, then the "
                      "SweepResult / per-lane SimResults",
 }
+# EFL-FG calls' graph-builder lockstep, counted from the stacked
+# ``graph_iters`` under ``engine.unpack``; trips - appends is
+# ``SweepResult.lockstep_waste``.
+_GRAPH_STEPS = {
+    "graph_appends": "EFL-FG graph-builder append steps the lanes needed "
+                     "(each lane's own productive steps)",
+    "graph_trips": "append steps their lockstep groups ran: each round, "
+                   "the group's slowest lane's steps in every lane",
+}
 SPANS = {
     "engine.run_sweep": ("span", "one run_sweep / run_sweep_sharded call"),
     **{f"engine.run_sweep/{k}": ("span", v) for k, v in _ENGINE_CALL.items()},
     "engine.run_sweep.items": ("counter", "lanes (seeds x budgets) swept"),
     "engine.run_sweep.compiled": ("counter",
                                   "run_sweep calls in which JAX compiled"),
+    **{f"engine.run_sweep.{k}": ("counter", v)
+       for k, v in _GRAPH_STEPS.items()},
     "engine.run_batch": ("span", "one run_batch call outside a dispatch"),
     **{f"engine.run_batch/{k}": ("span", v) for k, v in _ENGINE_CALL.items()},
     "engine.run_batch.items": ("counter", "lanes run, padding included"),
     "engine.run_batch.compiled": ("counter",
                                   "run_batch calls in which JAX compiled"),
+    **{f"engine.run_batch.{k}": ("counter", v)
+       for k, v in _GRAPH_STEPS.items()},
     "serve.dispatch": ("span", "one bucket on the dispatch thread, from "
                                "resolution to its futures settled"),
     "serve.dispatch.items": ("counter", "requests dispatched"),
@@ -92,6 +105,8 @@ SPANS = {
        for k, v in _ENGINE_CALL.items()},
     "serve.dispatch/engine.run_batch.items": ("counter",
                                               "lanes, padding included"),
+    **{f"serve.dispatch/engine.run_batch.{k}": ("counter", v)
+       for k, v in _GRAPH_STEPS.items()},
     "serve.dispatch/server.settle": ("span", "set_result / set_exception "
                                      "and the done-callbacks they run"),
     "server.plan": ("span", "planning a drain into buckets"),

@@ -279,6 +279,12 @@ class Span:
             _flush(self)
         return False
 
+    def count(self, name: str, n: int) -> None:
+        """Add ``n`` to the counter ``<path>.<name>`` when the tree
+        flushes, under the same rule as ``items``: not at all if JAX
+        compiled in the tree."""
+        self.root._buf.append((f"{self.path}.{name}", None, n))
+
     def record_requests(self, attrs: Optional[dict] = None) -> None:
         """Record each traced request's ``TRACER`` span now, from the
         span's start: at the latest when the span closes, earlier when
@@ -302,6 +308,9 @@ class _NoSpan:
 
     def __exit__(self, *exc) -> bool:
         return False
+
+    def count(self, name: str, n: int) -> None:
+        pass
 
     def record_requests(self, attrs: Optional[dict] = None) -> None:
         pass
@@ -330,6 +339,9 @@ def _flush(root: Span) -> None:
         SPANS.counter(f"{root.path}.compiled").inc()
         return
     for path, dur, items in root._buf:
+        if dur is None:                 # a count (Span.count)
+            SPANS.counter(path).inc(items)
+            continue
         SPANS.histogram(path, SPAN_BOUNDS).observe(dur)
         if items:
             SPANS.counter(f"{path}.items").inc(items)

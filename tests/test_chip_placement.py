@@ -41,10 +41,9 @@ def test_ops_refuse_interpret_mode_elsewhere(monkeypatch, backend):
     pe, ye = ce_ops.extend_stream(jnp.ones((K, 8)), jnp.ones(8), W)
     with pytest.raises(platform.UnsupportedPlatformError):
         ce_ops.client_eval(pe, ye, jnp.int32(0), jnp.int32(W),
-                           jnp.zeros(K), jnp.ones(K, bool), loss_scale=4.0,
-                           window=W)
+                           jnp.zeros(K), loss_scale=4.0, window=W)
     with pytest.raises(platform.UnsupportedPlatformError):
-        sr_ops.server_plan(jnp.zeros(K), jnp.zeros(K), jnp.full(K, 1e30),
+        sr_ops.server_plan(jnp.zeros(K), jnp.zeros(K), jnp.ones((K, K), bool),
                            jnp.ones(K), 3.0, jnp.zeros(K), 0.1)
 
 

@@ -29,6 +29,22 @@ def _case(K, n_stream, W, seed=0):
     return preds, y, pe, ye, rng
 
 
+def _mixture(kind, rng, K):
+    """A round's mixture as the round bodies hand it to the kernel:
+    ``log``, EFL-FG's eq.-(5) softmax of log-weights over the transmit
+    set; ``linear``, FedBoost's alpha renormalized over it; ``none``, a
+    distribution as given."""
+    sel = jnp.asarray(rng.integers(0, 2, K).astype(bool)).at[0].set(True)
+    if kind == "log":
+        log_w = jnp.asarray(rng.normal(0, 1, K).astype(np.float32))
+        return ensemble_mix_weights(log_w, sel)
+    alpha = jnp.asarray(rng.dirichlet(np.ones(K)).astype(np.float32))
+    if kind == "linear":
+        masked = jnp.where(sel, alpha, 0.0)
+        return masked / jnp.sum(masked)
+    return alpha
+
+
 # --- kernel vs jnp oracle -----------------------------------------------------
 
 @pytest.mark.parametrize("K,n_stream,W", [
@@ -38,22 +54,16 @@ def _case(K, n_stream, W, seed=0):
     (5, 30, 1),       # single-client window
     (7, 53, 53),      # window == stream length
 ])
-@pytest.mark.parametrize("weighting", ["log", "linear", "none"])
-def test_kernel_matches_ref(K, n_stream, W, weighting):
+@pytest.mark.parametrize("mixture", ["log", "linear", "none"])
+def test_kernel_matches_ref(K, n_stream, W, mixture):
     preds, y, pe, ye, rng = _case(K, n_stream, W, seed=K * W)
     for trial in range(6):
         cursor = jnp.int32(rng.integers(0, n_stream))
         n_t = jnp.int32(rng.integers(1, W + 1))
-        if weighting == "log":
-            w = jnp.asarray(rng.normal(0, 1, K).astype(np.float32))
-        else:
-            w = jnp.asarray(rng.dirichlet(np.ones(K)).astype(np.float32))
-        sel = jnp.asarray(rng.integers(0, 2, K).astype(bool)).at[0].set(True)
-        out = ce_ops.client_eval(pe, ye, cursor, n_t, w, sel,
-                                 loss_scale=4.0, window=W,
-                                 weighting=weighting)
-        ref = ce_ref.client_eval_ref(pe, ye, cursor, n_t, w, sel, 4.0, W,
-                                     weighting)
+        mix = _mixture(mixture, rng, K)
+        out = ce_ops.client_eval(pe, ye, cursor, n_t, mix,
+                                 loss_scale=4.0, window=W)
+        ref = ce_ref.client_eval_ref(pe, ye, cursor, n_t, mix, 4.0, W)
         for got, want, name in zip(out, ref, out._fields):
             np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                        rtol=1e-6, atol=1e-7, err_msg=name)
@@ -63,12 +73,11 @@ def test_kernel_empty_round_matches_ref():
     """n_t = 0: masked accumulators are exactly zero; the 0/0 mean and the
     inf*0 gradient are NaN in both the kernel and the oracle."""
     preds, y, pe, ye, rng = _case(6, 50, 8, seed=3)
-    w = jnp.asarray(rng.normal(0, 1, 6).astype(np.float32))
-    sel = jnp.ones(6, bool)
-    out = ce_ops.client_eval(pe, ye, jnp.int32(49), jnp.int32(0), w, sel,
-                             loss_scale=4.0, window=8, weighting="log")
-    ref = ce_ref.client_eval_ref(pe, ye, jnp.int32(49), jnp.int32(0), w,
-                                 sel, 4.0, 8, "log")
+    mix = _mixture("log", rng, 6)
+    out = ce_ops.client_eval(pe, ye, jnp.int32(49), jnp.int32(0), mix,
+                             loss_scale=4.0, window=8)
+    ref = ce_ref.client_eval_ref(pe, ye, jnp.int32(49), jnp.int32(0), mix,
+                                 4.0, 8)
     assert np.isnan(float(out.ens_sq_mean)) and np.isnan(
         float(ref.ens_sq_mean))
     assert float(out.ens_norm) == float(ref.ens_norm) == 0.0
@@ -81,8 +90,8 @@ def test_kernel_empty_round_matches_ref():
 
 @pytest.mark.parametrize("K,n_stream,W", [(22, 600, 100), (3, 29, 7)])
 def test_kernel_matches_unfused_ops(K, n_stream, W):
-    """Same numbers as `client_window_losses` + `fedboost_window_grad` +
-    `policy.ensemble_mix_weights` — the three ops the kernel fuses."""
+    """Same numbers as `client_window_losses` + `fedboost_window_grad`,
+    the two ops the kernel fuses, under EFL-FG's eq.-(5) mixture."""
     preds, y, pe, ye, rng = _case(K, n_stream, W, seed=11)
     pj, yj = jnp.asarray(preds), jnp.asarray(y)
     for trial in range(8):
@@ -90,13 +99,11 @@ def test_kernel_matches_unfused_ops(K, n_stream, W):
         n_t = jnp.int32(rng.integers(1, W + 1))
         log_w = jnp.asarray(rng.normal(0, 1, K).astype(np.float32))
         sel = jnp.asarray(rng.integers(0, 2, K).astype(bool)).at[0].set(True)
-        out = ce_ops.client_eval(pe, ye, cursor, n_t, log_w, sel,
-                                 loss_scale=4.0, window=W, weighting="log")
         mix = ensemble_mix_weights(log_w, sel)
+        out = ce_ops.client_eval(pe, ye, cursor, n_t, mix, loss_scale=4.0,
+                                 window=W)
         es, en, ml = client_window_losses(pj, yj, cursor, n_t, mix, 4.0, W)
         g = fedboost_window_grad(pj, yj, cursor, n_t, mix, W)
-        np.testing.assert_allclose(np.asarray(out.mix), np.asarray(mix),
-                                   rtol=1e-6, atol=1e-7)
         np.testing.assert_allclose(float(out.ens_sq_mean), float(es),
                                    rtol=1e-5)
         np.testing.assert_allclose(float(out.ens_norm), float(en), rtol=1e-5)
@@ -108,7 +115,8 @@ def test_kernel_matches_unfused_ops(K, n_stream, W):
 
 def test_kernel_matches_float64_numpy_oracle():
     """Independent host-side float64 implementation (the pre-engine client
-    evaluation), no jnp in the oracle path."""
+    evaluation), no jnp in the oracle path; the kernel takes the round
+    body's eq.-(5) mixture."""
     K, n_stream, W, loss_scale = 9, 71, 12, 4.0
     preds, y, pe, ye, rng = _case(K, n_stream, W, seed=21)
     for trial in range(20):
@@ -117,10 +125,9 @@ def test_kernel_matches_float64_numpy_oracle():
         log_w = rng.normal(0, 1, K).astype(np.float32)
         sel = rng.integers(0, 2, K).astype(bool)
         sel[int(rng.integers(0, K))] = True
+        got_mix = ensemble_mix_weights(jnp.asarray(log_w), jnp.asarray(sel))
         out = ce_ops.client_eval(pe, ye, jnp.int32(cursor), jnp.int32(n_t),
-                                 jnp.asarray(log_w), jnp.asarray(sel),
-                                 loss_scale=loss_scale, window=W,
-                                 weighting="log")
+                                 got_mix, loss_scale=loss_scale, window=W)
         lw = np.where(sel, log_w.astype(np.float64), -np.inf)
         mix = np.exp(lw - (np.log(np.sum(np.exp(lw - lw.max()))) + lw.max()))
         idx = np.arange(cursor, cursor + n_t) % n_stream
@@ -131,7 +138,7 @@ def test_kernel_matches_float64_numpy_oracle():
         yhat = mix @ p_cl
         ens_sq = (yhat - y_cl) ** 2
         grad = (2.0 / n_t) * (p_cl @ (yhat - y_cl))
-        np.testing.assert_allclose(np.asarray(out.mix), mix, rtol=1e-5,
+        np.testing.assert_allclose(np.asarray(got_mix), mix, rtol=1e-5,
                                    atol=1e-7)
         np.testing.assert_allclose(float(out.ens_sq_mean), ens_sq.mean(),
                                    rtol=1e-4)
@@ -253,9 +260,10 @@ def test_client_eval_properties_hypothesis():
         log_w = jnp.asarray(rng.normal(0, 1, K).astype(np.float32))
         sel = jnp.asarray(rng.integers(0, 2, K).astype(bool))
         sel = sel.at[int(rng.integers(0, K))].set(True)
-        out = ce_ops.client_eval(pe, ye, cursor, jnp.int32(n_t), log_w, sel,
-                                 loss_scale=4.0, window=W, weighting="log")
-        mix = np.asarray(out.mix)
+        mix = ensemble_mix_weights(log_w, sel)
+        out = ce_ops.client_eval(pe, ye, cursor, jnp.int32(n_t), mix,
+                                 loss_scale=4.0, window=W)
+        mix = np.asarray(mix)
         # eq.-(5) mixture: a distribution supported on the selected set
         assert np.all(mix >= -1e-7)
         np.testing.assert_allclose(mix.sum(), 1.0, atol=1e-5)

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 
 from repro.core import feedback_graph, feedback_graph_np
 from repro.core.domset import dominating_set, dominating_set_np
-from repro.core.graph import row_log_weight_sums
 
 K = 22
 
@@ -36,8 +35,8 @@ def _rand(seed, B=1):
     return log_w, costs
 
 
-def _solo(log_w, costs, budget, lps):
-    adj, it = feedback_graph(log_w, costs, budget, lps, with_iters=True)
+def _solo(log_w, costs, budget, prev):
+    adj, it = feedback_graph(log_w, costs, budget, prev, with_iters=True)
     return np.asarray(adj), int(it)
 
 
@@ -53,17 +52,17 @@ def test_vmap_bit_equal_to_solo_lanes_hetero_budgets(seed):
         np.concatenate([[0.05], np.linspace(1.0, 8.0, B - 2),
                         [float(np.sum(np.asarray(costs))) + 1.0]]),
         jnp.float32)
-    lps = jnp.full((B, K), 1e30, jnp.float32)
+    prev = jnp.ones((B, K, K), bool)
 
     vfg = jax.jit(jax.vmap(
         lambda lw, b, lp: feedback_graph(lw, costs, b, lp, with_iters=True),
         in_axes=(0, 0, 0)))
-    adj_b, it_b = jax.tree.map(np.asarray, vfg(log_w, budgets, lps))
+    adj_b, it_b = jax.tree.map(np.asarray, vfg(log_w, budgets, prev))
     dom_b = np.asarray(jax.jit(jax.vmap(dominating_set))(jnp.asarray(adj_b)))
 
     iters = []
     for i in range(B):
-        adj_s, it_s = _solo(log_w[i], costs, budgets[i], lps[i])
+        adj_s, it_s = _solo(log_w[i], costs, budgets[i], prev[i])
         assert (adj_b[i] == adj_s).all(), f"lane {i} adjacency diverged"
         assert int(it_b[i]) == it_s, f"lane {i} n_iters diverged"
         assert (dom_b[i] == np.asarray(dominating_set(adj_b[i]))).all()
@@ -78,16 +77,16 @@ def test_nested_vmap_grid_bit_equal_to_solo():
     n_b, n_s = 3, 4
     log_w, costs = _rand(7, n_s)
     budgets = jnp.asarray([1.0, 3.0, 9.0], jnp.float32)
-    lps = jnp.full((K,), 1e30, jnp.float32)
+    prev = jnp.ones((K, K), bool)
 
     grid = jax.jit(jax.vmap(jax.vmap(
-        lambda lw, b: feedback_graph(lw, costs, b, lps, with_iters=True),
+        lambda lw, b: feedback_graph(lw, costs, b, prev, with_iters=True),
         in_axes=(0, None)), in_axes=(None, 0)))
     adj_g, it_g = jax.tree.map(np.asarray, grid(log_w, budgets))
     assert adj_g.shape == (n_b, n_s, K, K)
     for bi in range(n_b):
         for si in range(n_s):
-            adj_s, it_s = _solo(log_w[si], costs, budgets[bi], lps)
+            adj_s, it_s = _solo(log_w[si], costs, budgets[bi], prev)
             assert (adj_g[bi, si] == adj_s).all()
             assert int(it_g[bi, si]) == it_s
 
@@ -96,18 +95,18 @@ def test_graph_iters_invariant_to_batch_composition():
     """A lane's n_iters (and bits) must not depend on its co-residents or
     the batch width — the invariance lockstep-waste accounting relies on."""
     log_w, costs = _rand(11, 4)
-    lps = jnp.full((4, K), 1e30, jnp.float32)
+    prev = jnp.ones((4, K, K), bool)
     budgets = jnp.asarray([0.2, 2.0, 5.0, 30.0], jnp.float32)
     vfg = jax.jit(jax.vmap(
         lambda lw, b, lp: feedback_graph(lw, costs, b, lp, with_iters=True),
         in_axes=(0, 0, 0)))
-    adj4, it4 = jax.tree.map(np.asarray, vfg(log_w, budgets, lps))
+    adj4, it4 = jax.tree.map(np.asarray, vfg(log_w, budgets, prev))
     # same lane pair embedded in a width-2 batch
     adj2, it2 = jax.tree.map(np.asarray,
-                             vfg(log_w[1:3], budgets[1:3], lps[1:3]))
+                             vfg(log_w[1:3], budgets[1:3], prev[1:3]))
     assert (adj4[1:3] == adj2).all() and (it4[1:3] == it2).all()
     for i in range(4):
-        adj_s, it_s = _solo(log_w[i], costs, budgets[i], lps[i])
+        adj_s, it_s = _solo(log_w[i], costs, budgets[i], prev[i])
         assert (adj4[i] == adj_s).all() and int(it4[i]) == it_s
 
 
@@ -127,8 +126,8 @@ def test_ineligible_leader_extreme_spread_matches_oracle():
         adj = np.asarray(feedback_graph(jnp.asarray(lw, jnp.float32),
                                         jnp.asarray(c, jnp.float32),
                                         jnp.float32(3.0),
-                                        jnp.full((Kk,), 1e30)))
-        adj_np = feedback_graph_np(np.exp(lw), c, 3.0, np.full(Kk, 1e30))
+                                        jnp.ones((Kk, Kk), bool)))
+        adj_np = feedback_graph_np(np.exp(lw), c, 3.0)
         assert (adj == adj_np).all(), f"seed {seed}"
 
 
@@ -143,11 +142,11 @@ def test_batched_oracle_agreement_random_cases():
         bud = float(r.uniform(1.0, 4.0) * c.max())
         lw = jnp.asarray(np.log(w), jnp.float32)
         cj = jnp.asarray(c, jnp.float32)
-        lps = jnp.full((Kk,), 1e30, jnp.float32)
+        prev = jnp.ones((Kk, Kk), bool)
         adj_b = np.asarray(jax.vmap(
-            lambda l: feedback_graph(l, cj, jnp.float32(bud), lps)
+            lambda l: feedback_graph(l, cj, jnp.float32(bud), prev)
         )(jnp.stack([lw, lw])))
-        adj_np = feedback_graph_np(w, c, bud, np.full(Kk, 1e30))
+        adj_np = feedback_graph_np(w, c, bud)
         assert (adj_b[0] == adj_np).all() and (adj_b[1] == adj_np).all()
 
 
@@ -172,27 +171,23 @@ def test_round_trip_trajectory_vmap_equals_solo():
     log_w0, costs = _rand(5, B)
     budgets = jnp.asarray([1.0, 2.5, 4.0, 8.0], jnp.float32)
 
-    def roll(log_w, bud, batched):
+    def roll(log_w, bud):
         def body(carry, _):
-            lw, lps = carry
-            adj, it = feedback_graph(lw, costs, bud, lps, with_iters=True)
+            lw, prev = carry
+            adj, it = feedback_graph(lw, costs, bud, prev, with_iters=True)
             dom = dominating_set(adj)
             lw = lw - 0.01 * (jnp.sum(adj, -1) + dom).astype(jnp.float32)
-            lps = (jax.vmap(row_log_weight_sums)(adj, lw) if batched
-                   else row_log_weight_sums(adj, lw))
-            return (lw, lps), (adj, dom, it)
-        shape = log_w.shape
-        _, outs = jax.lax.scan(body, (log_w, jnp.full(shape, 1e30)), None,
+            return (lw, adj), (adj, dom, it)
+        K = log_w.shape[-1]
+        _, outs = jax.lax.scan(body, (log_w, jnp.ones((K, K), bool)), None,
                                length=T)
         return outs
 
-    batched = jax.jit(jax.vmap(lambda lw, b: roll(lw, b, False),
-                               in_axes=(0, 0)))
+    batched = jax.jit(jax.vmap(roll, in_axes=(0, 0)))
     o_b = jax.tree.map(np.asarray, batched(log_w0, budgets))
     for i in range(B):
         o_s = jax.tree.map(np.asarray,
-                           jax.jit(lambda lw, b: roll(lw, b, False))(
-                               log_w0[i], budgets[i]))
+                           jax.jit(roll)(log_w0[i], budgets[i]))
         for got, want, name in zip((a[i] for a in o_b), o_s,
                                    ("adj", "dom", "iters")):
             assert (got == want).all(), f"lane {i} {name}"

@@ -277,6 +277,47 @@ def test_program_spans_are_catalogued_and_steady_after_warmup(
     assert "server.queue.wait_s" in stats["metrics"]["histograms"]
 
 
+def test_graph_lockstep_counters(stream_arrays):
+    """An EFL-FG call counts its graph builder's own append steps and
+    the steps its lockstep groups ran; their difference is the sweep's
+    ``lockstep_waste``, and a compacted batch's buckets are groups of
+    their own.  FedBoost builds no graph and counts nothing."""
+    from repro.federated import SimConfig, run_batch, run_sweep
+    preds, y, costs = stream_arrays
+    cfg = SimConfig()
+    sweep = ("eflfg", preds, y, costs, 24, cfg)
+    grid = dict(seeds=[1, 2], budgets=[0.5, 1.5, 3.0])
+    def steady(call, *args, **kw):
+        """The counts of one call made after a warm-up call."""
+        call(*args, **kw)
+        before = _counters()
+        out = call(*args, **kw)
+        after = _counters()
+        return out, {k: v - before.get(k, 0) for k, v in after.items()}
+
+    res, c = steady(run_sweep, *sweep, **grid)
+    appends = c["engine.run_sweep.graph_appends"]
+    assert appends == int(res.graph_iters.sum()) > 0
+    assert c["engine.run_sweep.graph_trips"] - appends \
+        == res.lockstep_waste > 0
+
+    # the same four lanes as a compacted batch: one bucket per budget
+    whole = run_sweep(*sweep, seeds=[1, 2], budgets=[0.5, 3.0])
+    _, c = steady(run_batch, *sweep, seeds=[1, 2, 1, 2],
+                  budgets=[0.5, 0.5, 3.0, 3.0])
+    appends = c["engine.run_batch.graph_appends"]
+    trips = c["engine.run_batch.graph_trips"]
+    assert appends == int(whole.graph_iters.sum())
+    assert trips == sum(2 * int(it.max(0).sum()) for it in whole.graph_iters)
+    # each bucket idles only behind its own slowest lane
+    assert trips - appends < whole.lockstep_waste
+
+    _, c = steady(run_sweep, "fedboost", preds, y, costs, 24, cfg,
+                  seeds=[1, 2])
+    assert c["engine.run_sweep.items"] == 2
+    assert not any("graph" in k for k in c if c[k])
+
+
 def test_only_the_dispatch_threads_queue_records_spans():
     """``server.wait_work`` / ``server.coalesce`` time the serve dispatch
     thread's waits; the daemon's admission queue polls without spans,

@@ -219,20 +219,17 @@ def test_fused_kernel_masked_matches_refs():
         cursor = int(rng.integers(0, n_stream))
         n_t = int(rng.integers(1, W + 1))
         mix = rng.dirichlet(np.ones(K)).astype(np.float32)
-        sel = rng.random(K) < 0.6
-        sel[int(rng.integers(K))] = True
         active = rng.random(W) < 0.7
         active[0] = True
         shift = float(rng.normal())
         ev = ops.client_eval(
             pe, ye, jnp.int32(cursor), jnp.int32(n_t), jnp.asarray(mix),
-            jnp.asarray(sel), loss_scale=loss_scale, window=W,
-            weighting="none", with_grad=True,
+            loss_scale=loss_scale, window=W, with_grad=True,
             active=jnp.asarray(active), shift=jnp.float32(shift))
         oracle = ref.client_eval_ref(
             pe, ye, jnp.int32(cursor), jnp.int32(n_t), jnp.asarray(mix),
-            jnp.asarray(sel), loss_scale, W, weighting="none",
-            active=jnp.asarray(active), shift=jnp.float32(shift))
+            loss_scale, W, active=jnp.asarray(active),
+            shift=jnp.float32(shift))
         np.testing.assert_allclose(float(ev.ens_sq_mean),
                                    float(oracle.ens_sq_mean), rtol=1e-5)
         np.testing.assert_allclose(np.asarray(ev.model_losses),
@@ -249,8 +246,7 @@ def test_fused_kernel_masked_matches_refs():
                                    rtol=1e-4, atol=1e-5)
     with pytest.raises(ValueError, match="both"):
         ops.client_eval(pe, ye, jnp.int32(0), jnp.int32(1),
-                        jnp.asarray(mix), jnp.asarray(sel),
-                        loss_scale=loss_scale, window=W, weighting="none",
+                        jnp.asarray(mix), loss_scale=loss_scale, window=W,
                         active=jnp.asarray(active))
 
 

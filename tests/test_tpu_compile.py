@@ -8,7 +8,9 @@ window W=100:
 
 * ``client_eval`` in its three variants (with / without the FedBoost
   gradient, and with the scenario schedule operands);
-* ``server_plan`` and ``server_update``;
+* ``server_plan`` and ``server_update``, and the EFL-FG scan through
+  them, with the float32 ``exp`` / ``log`` polynomials of
+  ``repro.core.numerics`` inside the Mosaic kernels;
 * the default (fused) EFL-FG scan, solo and as a 16-lane flat vmap;
 * the FedBoost budget x seed grid sweep, whose per-round transmit sets
   must be stacked with the round axis major.
@@ -94,15 +96,14 @@ def test_client_eval_compiles(one_chip, window, variant):
     L = extended_width(N_STREAM, window)
     s = lambda *a: _sds(one_chip, *a)
     args = [s((K, L)), s((L,)), s((), jnp.int32), s((), jnp.int32),
-            s((K,)), s((K,), jnp.bool_)]
+            s((K,))]
     kw = dict(loss_scale=4.0, window=window, interpret=False,
-              weighting="none" if variant == "grad" else "log",
               with_grad=variant == "grad")
     if variant == "scheduled":
         args += [s((window,), jnp.bool_), s(())]
 
         def fn(*a):
-            return client_eval_pallas(*a[:6], active=a[6], shift=a[7], **kw)
+            return client_eval_pallas(*a[:5], active=a[5], shift=a[6], **kw)
     else:
         def fn(*a):
             return client_eval_pallas(*a, **kw)
@@ -112,8 +113,8 @@ def test_client_eval_compiles(one_chip, window, variant):
 def test_server_plan_compiles(one_chip):
     s = lambda *a: _sds(one_chip, *a)
     _compile_check(lambda *a: server_plan_pallas(*a, interpret=False),
-                   s((K,)), s((K,)), s((K,)), s((K,)), s(()), s((K,)),
-                   s(()))
+                   s((K,)), s((K,)), s((K, K), jnp.bool_), s((K,)), s(()),
+                   s((K,)), s(()))
 
 
 def test_server_update_compiles(one_chip):
@@ -122,6 +123,17 @@ def test_server_update_compiles(one_chip):
                    s((K, K), jnp.bool_), s((K,)), s((K,), jnp.bool_),
                    s((), jnp.int32), s((K,)), s(()), s((K,)), s((K,)),
                    s(()))
+
+
+def test_fused_server_scan_compiles(one_chip, compiled_kernels):
+    """The EFL-FG scan with both rounds' halves in Mosaic kernels
+    (``use_fused_server``): a bitcast in ``numerics``' ``exp`` / ``log``
+    takes vectors only there."""
+    scan = engine._make_scan("eflfg", T, SimConfig(use_fused_server=True))
+    key = jax.eval_shape(lambda: jax.random.PRNGKey(0))
+    s = lambda *a: _sds(one_chip, *a)
+    _compile_check(scan, s((K, N_STREAM)), s((N_STREAM,)), s((K,)),
+                   s(key.shape, key.dtype), s(()))
 
 
 @pytest.mark.parametrize("lanes", [None, 16])
